@@ -39,6 +39,10 @@ match the measured rows at smaller K).  Fleet gates:
 ``zo_backend="ref"`` everywhere so mesh shapes compare the same per-step
 route (the fused-vs-ref axis is BENCH_zo_step's job).
 
+A CPU harness: each worker runs with ``JAX_PLATFORMS=cpu`` unless the
+caller set it, so its forced host meshes never wait on an accelerator.
+Its timings are XLA:CPU timings, not device numbers.
+
 Usage:
   PYTHONPATH=src python -m benchmarks.fl_scale_bench              # full grid
   PYTHONPATH=src python -m benchmarks.fl_scale_bench --smoke      # CI subset

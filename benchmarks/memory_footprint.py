@@ -7,7 +7,9 @@ numbers.  The backward pass must keep every layer's activations live
 (or pay remat recompute); the ZO dual forward keeps one layer period.
 
 The measurement runs in a subprocess because it needs the 512 forced host
-devices before jax initializes (benchmarks.run imports jax early).
+devices before jax initializes (benchmarks.run imports jax early).  The
+child is a host-device dry run: it pins ``JAX_PLATFORMS=cpu``, so it never
+waits on an accelerator its parent holds.
 """
 from __future__ import annotations
 
@@ -19,6 +21,7 @@ import sys
 
 _CHILD = r"""
 import os
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 import json
 from repro.launch.dryrun import build_lowerable

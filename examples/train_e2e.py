@@ -29,11 +29,12 @@ from repro.models import Model
 
 SMALL = ModelConfig(name="llama-8m", family="dense", n_layers=4, d_model=256,
                     n_heads=4, n_kv_heads=2, d_ff=704, vocab=2048,
-                    tie_embeddings=True, source="llama-3.2 family, CPU-scaled")
+                    tie_embeddings=True, source="llama-3.2 family, CPU-scaled",
+                    dtype="float32")
 LARGE = ModelConfig(name="llama-110m", family="dense", n_layers=12,
                     d_model=768, n_heads=12, n_kv_heads=4, d_ff=2048,
                     vocab=32_000, tie_embeddings=True,
-                    source="llama-3.2 family, 100M-class")
+                    source="llama-3.2 family, 100M-class", dtype="float32")
 
 
 def main():

@@ -49,7 +49,7 @@ def _dtype_bad() -> Built:
 
     # f64 avals require x64 mode, which this process keeps off — trace
     # the jaxpr under the scoped enable and hand it to the rule directly
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         jaxpr = jax.make_jaxpr(fn)(jnp.ones((8,), jnp.float32),
                                    jnp.ones((8,), jnp.float64))
     x = jnp.ones((8,), jnp.float32)

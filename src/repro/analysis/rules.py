@@ -48,7 +48,9 @@ LOWP_DTYPES = ("bfloat16", "float16")
 # here means silently lossy accumulation.
 REDUCE_PRIMS = ("reduce_sum", "cumsum", "dot_general", "add_any",
                 "reduce_window_sum", "reduce_prod")
-HOST_SYNC_PRIMS = ("infeed", "outfeed")
+# jax.debug.print lowers to its own ``debug_print`` primitive, not a
+# ``*callback`` one
+HOST_SYNC_PRIMS = ("infeed", "outfeed", "debug_print")
 
 
 def check_no_dense_intermediates(jaxpr, S: int, limit: int = 2,
@@ -128,8 +130,8 @@ class DtypeDriftRule(Rule):
 
 class HostSyncRule(Rule):
     """No host round-trips inside jitted hot paths: ``pure_callback`` /
-    ``io_callback`` / ``debug_callback`` (jax.debug.print) equations and
-    infeed/outfeed all serialize the device stream against Python —
+    ``io_callback`` / ``debug_callback`` / ``debug_print`` (jax.debug.print)
+    equations and infeed/outfeed all serialize the device stream against Python —
     at decode-step or ZO-step granularity one stray print costs more
     than the step."""
 

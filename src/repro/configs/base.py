@@ -92,6 +92,7 @@ class ModelConfig:
     lora_alpha: float = 16.0
     # citation for the config
     source: str = ""
+    # weight dtype of Model.init (published widths run in bf16)
     dtype: str = "bfloat16"
 
     @property
@@ -126,7 +127,7 @@ class ModelConfig:
 
     def reduced(self) -> "ModelConfig":
         """Smoke-test variant of the same family: <=2 periods, d_model<=256,
-        <=4 experts, tiny vocab."""
+        <=4 experts, tiny vocab, float32 weights (the CPU tests' numbers)."""
         d_model = min(self.d_model, 256)
         n_heads = min(self.n_heads, 4)
         n_kv = max(1, min(self.n_kv_heads, n_heads))
@@ -143,6 +144,7 @@ class ModelConfig:
             d_ff=min(self.d_ff, 512) if self.d_ff else 0,
             vocab=min(self.vocab, 512),
             sliding_window=min(self.sliding_window, 32) if self.sliding_window else 0,
+            dtype="float32",
         )
         if self.moe is not None:
             kw["moe"] = dataclasses.replace(

@@ -12,6 +12,7 @@ TINY = ModelConfig(
     vocab=512,
     tie_embeddings=True,
     source="test",
+    dtype="float32",
 )
 
 TINY_LORA = TINY.replace(name="tiny-lora", lora_rank=4)

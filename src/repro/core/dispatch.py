@@ -222,38 +222,45 @@ def get_backing(space, template) -> FlatBacking:
     return backing
 
 
-# auto stays on the pytree route when the flat path would materialize more
-# dense state than this, *summed over vmapped clients* (the T>1 loops scan
-# a dense [n_pad] f32 delta per client, T=1 steps hold a handful of dense
-# transients; ref touches only sparse [n] vectors and in-place scatters).
-# The budget is platform-scaled: CPU simulations get 256 MiB, a real TPU
-# (where the flat route is the point) gets 8 GiB of HBM headroom.
-# Explicit backend="pallas" always overrides.
-DENSE_CARRY_AUTO_BYTES = 256 * 1024 * 1024
-DENSE_CARRY_AUTO_BYTES_TPU = 8 * 1024 * 1024 * 1024
+# What the flat route holds per client, in dense [n_pad] vectors of at most
+# 4 bytes a coordinate: the flat weights, the dense delta and z buffers,
+# their sum, and the two perturbed copies (T=1 steps hold fewer; the
+# estimate is an upper bound).  The ref route touches only sparse [n]
+# vectors and in-place scatters.
+FLAT_ROUTE_BYTES_PER_COORD = 6 * 4
+# XLA:CPU reports no device memory; CPU simulations cap the flat route at
+# 256 MiB of dense carry (one f32 vector per client), i.e. this budget
+HOST_FLAT_ROUTE_BYTES = 6 * 256 * 1024 * 1024
 
 
-def _carry_budget() -> int:
-    return (DENSE_CARRY_AUTO_BYTES_TPU
-            if jax.default_backend() == "tpu" else DENSE_CARRY_AUTO_BYTES)
+def _flat_route_budget() -> int:
+    """Bytes the flat route may take: what the default device reports
+    free (``bytes_limit - bytes_in_use``), or the host cap where it
+    reports nothing."""
+    stats = jax.devices()[0].memory_stats()
+    if not stats or "bytes_limit" not in stats:
+        return HOST_FLAT_ROUTE_BYTES
+    return int(stats["bytes_limit"]) - int(stats.get("bytes_in_use", 0))
 
 
 def resolve_backend(backend: Optional[str], backing: FlatBacking, *,
                     sharded: bool = False, dense_carry: int = 1) -> str:
     """Map a requested backend ('auto'/None included) to 'pallas' | 'ref'.
 
-    ``dense_carry`` is the number of concurrent dense [n_pad] f32 state
-    vectors the pallas route implies — one per vmapped client in
-    make_local_run / make_fl_round_step, one for a single T=1 step.  Auto
-    requires their total to fit the platform carry budget so huge
-    unsharded models don't trade sparse [n] traffic for an OOM."""
+    ``dense_carry`` is the number of clients whose flat-route state may be
+    live at once — one per client of a group in make_local_run /
+    make_fl_round_step, one for a single T=1 step.  Auto requires their
+    total (``FLAT_ROUTE_BYTES_PER_COORD`` per coordinate each) to fit the
+    device's free memory, so a model at published width never trades
+    sparse [n] traffic for an out-of-memory dense route."""
     backend = backend or "auto"
     if backend not in BACKENDS:
         raise ValueError(f"backend must be one of {BACKENDS}, got {backend!r}")
     if backend == "auto":
         if sharded or not backing.supported:
             return "ref"
-        if 4 * backing.n_pad * max(1, dense_carry) > _carry_budget():
+        if (FLAT_ROUTE_BYTES_PER_COORD * backing.n_pad * max(1, dense_carry)
+                > _flat_route_budget()):
             return "ref"
         return "pallas"
     return backend

@@ -7,6 +7,7 @@ transferable across downstream tasks.
 """
 from __future__ import annotations
 
+import functools
 from typing import Callable, Iterable
 
 import jax
@@ -20,8 +21,25 @@ def _n_select(total: int, density: float) -> int:
     return max(1, int(round(total * density)))
 
 
+@functools.partial(jax.jit, donate_argnums=0)
+def _accumulate_square(acc, g):
+    return jax.tree.map(lambda a, gg: a + jnp.square(gg.astype(jnp.float32)),
+                        acc, g)
+
+
+@functools.partial(jax.jit, static_argnums=1, donate_argnums=0)
+def _divide(acc, n: int):
+    return jax.tree.map(lambda a: a / n, acc)
+
+
 def sensitivity_scores(loss_fn: Callable, params, batches: Iterable):
-    """Average squared per-parameter gradient over pre-training batches."""
+    """Average squared per-parameter gradient over pre-training batches.
+
+    The f32 accumulator is updated in place (donated), so at published
+    width the device holds the weights, one gradient and one accumulator
+    (Qwen2-1.5B in bf16: 3.1 + 3.1 + 6.2 GB), never two accumulators.
+    (Squaring inside the gradient program instead needs more: XLA then
+    keeps f32 gradient temporaries beside the accumulator.)"""
     from repro.models.layers import differentiable_attn
     grad_fn = jax.jit(jax.grad(loss_fn))
     acc = jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32), params)
@@ -29,26 +47,36 @@ def sensitivity_scores(loss_fn: Callable, params, batches: Iterable):
     for batch in batches:
         with differentiable_attn():  # grad-appropriate attn route
             g = grad_fn(params, batch)
-        acc = jax.tree.map(lambda a, gg: a + jnp.square(gg.astype(jnp.float32)),
-                           acc, g)
+        acc = _accumulate_square(acc, g)
+        del g
         n += 1
-    return jax.tree.map(lambda a: a / max(n, 1), acc)
+    return _divide(acc, max(n, 1))
 
 
 def _global_topk_indices(score_tree, density: float):
-    """Per-leaf int32 flat-index arrays of the global top-k scores."""
+    """Per-leaf int32 flat-index arrays of the global top-k scores.
+
+    Exactly k indices: every score above the k-th largest, then the
+    lowest-index ties at it.  The selection runs on the host over one
+    f32 copy of the scores plus one partition buffer (no int64 index
+    array of the full model)."""
     leaves, treedef = jax.tree_util.tree_flatten(score_tree)
     sizes = [int(np.prod(l.shape)) for l in leaves]
-    total = sum(sizes)
-    k = _n_select(total, density)
-    flat = np.concatenate([np.asarray(l, np.float32).ravel() for l in leaves])
-    top = np.argpartition(flat, -k)[-k:]
-    top = np.sort(top)
     offsets = np.concatenate([[0], np.cumsum(sizes)])
+    total = int(offsets[-1])
+    k = _n_select(total, density)
+    flat = np.empty((total,), np.float32)
+    for l, o, s in zip(leaves, offsets[:-1], sizes):
+        flat[o:o + s] = np.asarray(l, np.float32).ravel()
+    kth = np.partition(flat, total - k)[total - k]
+    top = np.flatnonzero(flat > kth)
+    ties = np.flatnonzero(flat == kth)[:k - top.size]
+    top = np.sort(np.concatenate([top, ties]))
+    del flat
     idx_leaves = []
     for i in range(len(leaves)):
-        sel = top[(top >= offsets[i]) & (top < offsets[i + 1])] - offsets[i]
-        idx_leaves.append(jnp.asarray(sel, jnp.int32))
+        lo, hi = np.searchsorted(top, [offsets[i], offsets[i + 1]])
+        idx_leaves.append(jnp.asarray(top[lo:hi] - offsets[i], jnp.int32))
     return jax.tree_util.tree_unflatten(treedef, idx_leaves)
 
 

@@ -38,6 +38,7 @@ K x model (``checkpoint/state.server_state_sizes`` accounts it).
 """
 from __future__ import annotations
 
+import time
 from dataclasses import dataclass
 from typing import Any, Callable, Dict, List, Optional, Sequence
 
@@ -50,6 +51,7 @@ from repro.core import seeds as S
 from repro.core import virtual_path as VP
 from repro.core import vpcs as VPCS
 from repro.core import zo as ZO
+from repro.core.dispatch import get_backing, resolve_backend
 from repro.core.gradip import gradip_trajectory
 from repro.core.quantize import make_codec
 from repro.core.sampling import ClientSampler
@@ -166,7 +168,13 @@ class FederatedZO:
         # gip_idx, gs) — part of the checkpointed state (DESIGN.md §11)
         self._pending: List[dict] = []
         self.last_round_info: Optional[dict] = None
-        self._batch_runs: Dict[int, Callable] = {}
+        self._batch_runs: Dict[tuple, Callable] = {}
+        # (T, group width) -> the ZO route ("pallas" | "ref") its program
+        # runs, and the host seconds of each round ``run`` completed
+        self.zo_routes: Dict[tuple, str] = {}
+        self.round_seconds: List[float] = []
+        # per-client GradIP [T_cali] of the last calibrate_vp call
+        self.vp_trajectories: Optional[List[np.ndarray]] = None
         self._recon = jax.jit(
             lambda keys, gs: jax.vmap(
                 lambda g: VP.reconstruct_delta(self.space, keys, g,
@@ -191,12 +199,16 @@ class FederatedZO:
         (``compute_view``) — allclose-level parity only."""
         key = (T, n_group)
         if key not in self._batch_runs:
+            # resolve the ZO route here, once per group program, so the
+            # route each group took is observable (``zo_routes``)
+            route = resolve_backend(
+                self.backend, get_backing(self.space, self.params),
+                sharded=self.plan is not None, dense_carry=n_group)
+            self.zo_routes[key] = route
             run = ZO.make_local_run(self.loss_fn, self.space, self.fl.eps,
                                     self.fl.lr,
                                     n_dirs=getattr(self.fl, "n_dirs", 1),
-                                    backend=self.backend,
-                                    n_carries=n_group,
-                                    sharded=self.plan is not None,
+                                    backend=route,
                                     quantize=self.codec.jax_spec())
 
             def group(params, keys, batches):
@@ -443,6 +455,7 @@ class FederatedZO:
             c.ptr = 0  # calibration does not consume training order
         results, flagged = VPCS.select_clients(trajs, self.fl)
         self.early_stopped = set(flagged)
+        self.vp_trajectories = trajs
         return results, flagged, trajs
 
     def early_stop_random(self, n: int, seed: int = 0):
@@ -469,6 +482,17 @@ class FederatedZO:
         return restore_server_state(path, self)
 
     # -- training loop -------------------------------------------------------
+    def evaluate(self, batch) -> Dict[str, float]:
+        """``eval_fn`` on the current parameters, as host floats.
+
+        Under a ``plan`` the parameters are replicated first: left
+        FSDP-sharded, the compiler splits the eval matmuls over sharded
+        contraction dims and the metrics differ from the single-device
+        run in the last bits (the mesh-parity invariant, DESIGN.md §9)."""
+        params = (self.params if self.plan is None
+                  else self.plan.place_replicated(self.params))
+        return {k: float(v) for k, v in self.eval_fn(params, batch).items()}
+
     def run(self, rounds: int, eval_every: int = 0, eval_batch=None,
             gp_vec=None, verbose: bool = False, fault_plan=None,
             checkpoint_dir=None, checkpoint_every: int = 0):
@@ -488,11 +512,13 @@ class FederatedZO:
         for _ in range(rounds):
             faults = (fault_plan.round_faults(self.round)
                       if fault_plan is not None else None)
+            t0 = time.perf_counter()
             self.run_round(gp_vec=gp_vec, faults=faults)
+            jax.block_until_ready(self.params)
+            self.round_seconds.append(time.perf_counter() - t0)
             if eval_every and self.round % eval_every == 0 \
                     and self.eval_fn is not None:
-                m = self.eval_fn(self.params, eval_batch)
-                m = {k: float(v) for k, v in m.items()}
+                m = self.evaluate(eval_batch)
                 m["round"] = self.round
                 self.history.append(m)
                 if verbose:

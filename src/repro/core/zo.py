@@ -150,17 +150,15 @@ def _local_step_ref(loss_fn, params, space, delta, key, eps, lr, batch,
 
 def make_local_run(loss_fn: Callable, space, eps: float, lr: float,
                    n_dirs: int = 1, backend: Optional[str] = None,
-                   n_carries: int = 1, sharded: bool = False,
+                   sharded: bool = False,
                    quantize=None):
     """Jittable T-step client loop.
 
     batches: pytree with leading [T, ...]; keys: [T] PRNG keys.
     Returns (delta_T [n], gs [T]) (gs: [T, K] when n_dirs > 1).
-    ``n_carries``: how many copies of this run will be vmapped at once
-    (clients) — the auto backend budgets its dense flat carries by it.
-    ``sharded=True`` (the mesh route of ``FederatedZO``) forces
-    ``backend="auto"`` onto the pytree route, whose N-D scatters keep the
-    weight leaves sharded (DESIGN.md §9).
+    ``sharded=True`` declares mesh-sharded parameters: ``backend="auto"``
+    then takes the pytree route, whose N-D scatters keep the weight
+    leaves sharded (DESIGN.md §9).
     ``quantize`` (:class:`repro.core.quantize.QuantSpec`) turns on
     exact-replay uplink quantization: each step's g is rounded to the
     wire grid before it is applied *and* before it is returned, so the
@@ -173,8 +171,7 @@ def make_local_run(loss_fn: Callable, space, eps: float, lr: float,
 
     def run(params, keys, batches, delta0):
         backing = get_backing(space, params)
-        if resolve_backend(backend, backing, sharded=sharded,
-                           dense_carry=max(1, n_carries)) == "ref":
+        if resolve_backend(backend, backing, sharded=sharded) == "ref":
             def step(delta, inp):
                 key, batch = inp
                 delta, g = _local_step_ref(loss_fn, params, space, delta,

@@ -31,6 +31,7 @@ NEG_INF = -1e30
 def _decode_attn_kernel(L_ref, q_ref, k_ref, v_ref, o_ref,
                         m_scr, l_scr, acc_scr, *, block_s: int, scale: float,
                         softcap: float):
+    b = pl.program_id(0)
     i = pl.program_id(2)
 
     @pl.when(i == 0)
@@ -40,13 +41,13 @@ def _decode_attn_kernel(L_ref, q_ref, k_ref, v_ref, o_ref,
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
     q = q_ref[0, 0].astype(jnp.float32)          # [G, dh]
-    k = k_ref[0, :, 0].astype(jnp.float32)       # [Sblk, dh]
-    v = v_ref[0, :, 0].astype(jnp.float32)       # [Sblk, dh]
+    k = k_ref[0].astype(jnp.float32)             # [Sblk, dh]
+    v = v_ref[0].astype(jnp.float32)             # [Sblk, dh]
     s = jnp.dot(q, k.T) * scale                  # [G, Sblk]
     if softcap:
         s = jnp.tanh(s / softcap) * softcap
     pos = i * block_s + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    s = jnp.where(pos < L_ref[0], s, NEG_INF)
+    s = jnp.where(pos < L_ref[b], s, NEG_INF)
 
     m_prev = m_scr[...]                           # [G, 1]
     m_new = jnp.maximum(m_prev[:, 0], s.max(axis=-1))[:, None]
@@ -68,6 +69,10 @@ def decode_attention(q, k, v, length, *, block_s: int = 512,
 
     Returns [B, KVH, G, dh] attention output (softmax over positions <
     length, with optional pre-mask tanh softcapping of the logits).
+
+    The cache is read as ``[B, S, KVH*dh]`` (a free reshape) so each K/V
+    block is a ``(block_s, dh)`` tile of one head: a legal TPU block when
+    dh is a multiple of 128.  The per-row lengths sit whole in SMEM.
     """
     B, KVH, G, dh = q.shape
     S = k.shape[1]
@@ -77,14 +82,15 @@ def decode_attention(q, k, v, length, *, block_s: int = 512,
     L_arr = jnp.broadcast_to(jnp.asarray(length, jnp.int32).reshape(-1), (B,))
     kernel = functools.partial(_decode_attn_kernel, block_s=block_s,
                                scale=scale, softcap=float(softcap))
+    kv_spec = pl.BlockSpec((1, block_s, dh), lambda b, h, i: (b, i, h))
     return pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1,), lambda b, h, i: (b,)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, 1, G, dh), lambda b, h, i: (b, h, 0, 0)),
-            pl.BlockSpec((1, block_s, 1, dh), lambda b, h, i: (b, i, h, 0)),
-            pl.BlockSpec((1, block_s, 1, dh), lambda b, h, i: (b, i, h, 0)),
+            kv_spec,
+            kv_spec,
         ],
         out_specs=pl.BlockSpec((1, 1, G, dh), lambda b, h, i: (b, h, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B, KVH, G, dh), q.dtype),
@@ -94,4 +100,4 @@ def decode_attention(q, k, v, length, *, block_s: int = 512,
             pltpu.VMEM((G, dh), jnp.float32),  # value accumulator
         ],
         interpret=interpret,
-    )(L_arr, q, k, v)
+    )(L_arr, q, k.reshape(B, S, KVH * dh), v.reshape(B, S, KVH * dh))

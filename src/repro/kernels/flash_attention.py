@@ -11,9 +11,11 @@ GQA layout: queries are grouped per KV head ([B, KVH, S, G, dh] — no KV
 repeat; the G query heads of a group share one K/V stream).  The forward
 grid is (B, KVH, S/block_q, S/block_k) with the KV-block axis innermost
 (sequential accumulation into the running max / normalizer / value scratch,
-exactly the flash-decode recurrence).  Inside a block the G axis is folded
-into the query rows so the score matmul is a single [block_q*G, dh] x
-[dh, block_k] MXU contraction.
+exactly the flash-decode recurrence).  The wrapper folds the G axis into
+the query rows before the kernels see it ([B, KVH, S*G, dh], a free
+reshape; row r is query r // G), so the score matmul is a single
+[block_q*G, dh] x [dh, block_k] MXU contraction and no kernel reshapes
+in-register.  Per-row lengths sit whole in SMEM.
 
 Forward-attention contract (the hot path of ``models/layers`` routed via
 ``resolve_attn_backend``):
@@ -70,6 +72,7 @@ NEG_INF = -1e30
 
 class Static(NamedTuple):
     """Hashable non-diff config threaded through the custom_vjp."""
+    G: int
     block_q: int
     block_k: int
     window: int
@@ -106,6 +109,7 @@ def _flash_attn_kernel(L_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
                        m_scr, l_scr, acc_scr, *, block_q: int, block_k: int,
                        G: int, scale: float, softcap: float, window: int,
                        causal: bool):
+    L0 = L_ref[pl.program_id(0)]
     i = pl.program_id(2)   # query block
     j = pl.program_id(3)   # KV block (innermost: sequential accumulation)
     q0 = i * block_q
@@ -119,20 +123,18 @@ def _flash_attn_kernel(L_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
 
     # Block-level pruning: a KV block with no live (query, key) pair
     # contributes nothing to the running stats — skip its matmuls.
-    needed = _block_needed(L_ref[0], q0, k0, block_q=block_q,
+    needed = _block_needed(L0, q0, k0, block_q=block_q,
                            block_k=block_k, window=window, causal=causal)
 
     @pl.when(needed)
     def _accumulate():
-        q = q_ref[0, 0].astype(jnp.float32)      # [block_q, G, dh]
-        dh = q.shape[-1]
-        q2 = q.reshape(block_q * G, dh)          # row r <-> query q0 + r//G
+        q2 = q_ref[0, 0].astype(jnp.float32)     # [block_q*G, dh]
         k = k_ref[0, 0].astype(jnp.float32)      # [block_k, dh]
         v = v_ref[0, 0].astype(jnp.float32)      # [block_k, dh]
         s = jnp.dot(q2, k.T, preferred_element_type=jnp.float32) * scale
         if softcap:
             s = jnp.tanh(s / softcap) * softcap
-        valid = _valid_mask(L_ref[0], q0, k0, s.shape, G=G, window=window,
+        valid = _valid_mask(L0, q0, k0, s.shape, G=G, window=window,
                             causal=causal)
         s = jnp.where(valid, s, NEG_INF)
         m_prev = m_scr[...]                       # [block_q*G, 1]
@@ -149,39 +151,46 @@ def _flash_attn_kernel(L_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
     @pl.when(j == pl.num_programs(3) - 1)
     def _finalize():
         out = acc_scr[...] / jnp.maximum(l_scr[...], 1e-30)
-        o_ref[0, 0] = out.reshape(block_q, G, -1).astype(o_ref.dtype)
+        o_ref[0, 0] = out.astype(o_ref.dtype)
         # per-row logsumexp residual: exp(s - lse) is the final normalized
         # probability, the only softmax state the backward needs
-        lse = m_scr[...] + jnp.log(jnp.maximum(l_scr[...], 1e-30))
-        lse_ref[0, 0] = lse.reshape(block_q, G)
+        lse_ref[0, 0] = m_scr[...] + jnp.log(jnp.maximum(l_scr[...], 1e-30))
+
+
+def _row_specs(st: Static, dh: int, q_index, kv_index):
+    """BlockSpecs of the G-folded layout: query rows, K/V, per-row stats."""
+    rows = st.block_q * st.G
+    return (pl.BlockSpec((1, 1, rows, dh), q_index),
+            pl.BlockSpec((1, 1, st.block_k, dh), kv_index),
+            pl.BlockSpec((1, 1, rows, 1), q_index))
 
 
 def _fwd_call(st: Static, q, k, v, L_arr):
-    """pallas_call for the forward; returns (out, lse [B,KVH,S,G] f32)."""
-    B, KVH, S, G, dh = q.shape
+    """pallas_call for the forward on G-folded queries [B,KVH,S*G,dh];
+    returns (out [B,KVH,S*G,dh], lse [B,KVH,S*G,1] f32)."""
+    B, KVH, SG, dh = q.shape
+    S = SG // st.G
     grid = (B, KVH, S // st.block_q, S // st.block_k)
     kernel = functools.partial(
-        _flash_attn_kernel, block_q=st.block_q, block_k=st.block_k, G=G,
+        _flash_attn_kernel, block_q=st.block_q, block_k=st.block_k, G=st.G,
         scale=dh ** -0.5, softcap=float(st.softcap), window=int(st.window),
         causal=bool(st.causal))
-    kv_spec = pl.BlockSpec((1, 1, st.block_k, dh),
-                           lambda b, h, i, j: (b, h, j, 0))
-    q_spec = pl.BlockSpec((1, 1, st.block_q, G, dh),
-                          lambda b, h, i, j: (b, h, i, 0, 0))
-    lse_spec = pl.BlockSpec((1, 1, st.block_q, G),
-                            lambda b, h, i, j: (b, h, i, 0))
+    q_spec, kv_spec, lse_spec = _row_specs(
+        st, dh, lambda b, h, i, j: (b, h, i, 0),
+        lambda b, h, i, j: (b, h, j, 0))
+    rows = st.block_q * st.G
     return pl.pallas_call(
         kernel,
         grid=grid,
-        in_specs=[pl.BlockSpec((1,), lambda b, h, i, j: (b,)),
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),
                   q_spec, kv_spec, kv_spec],
         out_specs=[q_spec, lse_spec],
-        out_shape=[jax.ShapeDtypeStruct((B, KVH, S, G, dh), q.dtype),
-                   jax.ShapeDtypeStruct((B, KVH, S, G), jnp.float32)],
+        out_shape=[jax.ShapeDtypeStruct((B, KVH, SG, dh), q.dtype),
+                   jax.ShapeDtypeStruct((B, KVH, SG, 1), jnp.float32)],
         scratch_shapes=[
-            pltpu.VMEM((st.block_q * G, 1), jnp.float32),   # running max m
-            pltpu.VMEM((st.block_q * G, 1), jnp.float32),   # normalizer l
-            pltpu.VMEM((st.block_q * G, dh), jnp.float32),  # value acc
+            pltpu.VMEM((rows, 1), jnp.float32),   # running max m
+            pltpu.VMEM((rows, 1), jnp.float32),   # normalizer l
+            pltpu.VMEM((rows, dh), jnp.float32),  # value acc
         ],
         interpret=st.interpret,
     )(L_arr, q, k, v)
@@ -189,39 +198,35 @@ def _fwd_call(st: Static, q, k, v, L_arr):
 
 # ------------------------------------------------------------ backward ----
 def _recompute_p_ds(L0, q_ref, k_ref, v_ref, lse_ref, delta_ref, do_ref,
-                    q0, k0, *, block_q, block_k, G, scale, softcap, window,
-                    causal):
+                    q0, k0, *, block_k, G, scale, softcap, window, causal):
     """Shared backward block math: recompute p and ds for one
-    (query-block, KV-block) tile.  Returns (p, ds, q2, k, do2), every
-    operand f32 with the G axis folded into rows."""
-    q = q_ref[0, 0].astype(jnp.float32)          # [block_q, G, dh]
-    dh = q.shape[-1]
-    q2 = q.reshape(block_q * G, dh)
+    (query-block, KV-block) tile.  Returns (p, ds, q, k, do), every
+    operand f32; query rows arrive G-folded (row r <-> query q0 + r//G)."""
+    q = q_ref[0, 0].astype(jnp.float32)          # [block_q*G, dh]
     k = k_ref[0, 0].astype(jnp.float32)          # [block_k, dh]
     v = v_ref[0, 0].astype(jnp.float32)          # [block_k, dh]
-    do = do_ref[0, 0].astype(jnp.float32).reshape(block_q * G, dh)
-    s = jnp.dot(q2, k.T, preferred_element_type=jnp.float32) * scale
+    do = do_ref[0, 0].astype(jnp.float32)        # [block_q*G, dh]
+    s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
     if softcap:
         s = jnp.tanh(s / softcap) * softcap
     valid = _valid_mask(L0, q0, k0, s.shape, G=G, window=window,
                         causal=causal)
-    lse = lse_ref[0, 0].reshape(block_q * G, 1)  # f32
     # explicit zero where invalid: on fully-masked rows lse is ~NEG_INF and
     # exp(s - lse) would overflow / evaluate to 1 at masked s, not 0
-    p = jnp.where(valid, jnp.exp(s - lse), 0.0)
+    p = jnp.where(valid, jnp.exp(s - lse_ref[0, 0]), 0.0)
     dp = jnp.dot(do, v.T, preferred_element_type=jnp.float32)
-    delta = delta_ref[0, 0].reshape(block_q * G, 1)
-    ds = p * (dp - delta)
+    ds = p * (dp - delta_ref[0, 0])
     if softcap:
         # s here is the *capped* logit: d tanh-cap/d raw = 1 - (s/cap)^2
         ds = ds * (1.0 - jnp.square(s / softcap))
-    return p, ds, q2, k, do
+    return p, ds, q, k, do
 
 
 def _flash_attn_bwd_dq_kernel(L_ref, q_ref, k_ref, v_ref, lse_ref, delta_ref,
                               do_ref, dq_ref, dq_scr, *, block_q: int,
                               block_k: int, G: int, scale: float,
                               softcap: float, window: int, causal: bool):
+    L0 = L_ref[pl.program_id(0)]
     i = pl.program_id(2)   # query block
     j = pl.program_id(3)   # KV block (innermost: accumulate dq)
     q0 = i * block_q
@@ -231,21 +236,21 @@ def _flash_attn_bwd_dq_kernel(L_ref, q_ref, k_ref, v_ref, lse_ref, delta_ref,
     def _init():
         dq_scr[...] = jnp.zeros_like(dq_scr)
 
-    needed = _block_needed(L_ref[0], q0, k0, block_q=block_q,
+    needed = _block_needed(L0, q0, k0, block_q=block_q,
                            block_k=block_k, window=window, causal=causal)
 
     @pl.when(needed)
     def _accumulate():
         _, ds, _, k, _ = _recompute_p_ds(
-            L_ref[0], q_ref, k_ref, v_ref, lse_ref, delta_ref, do_ref,
-            q0, k0, block_q=block_q, block_k=block_k, G=G, scale=scale,
+            L0, q_ref, k_ref, v_ref, lse_ref, delta_ref, do_ref,
+            q0, k0, block_k=block_k, G=G, scale=scale,
             softcap=softcap, window=window, causal=causal)
         dq_scr[...] += jnp.dot(ds, k,
                                preferred_element_type=jnp.float32) * scale
 
     @pl.when(j == pl.num_programs(3) - 1)
     def _finalize():
-        dq_ref[0, 0] = dq_scr[...].reshape(block_q, G, -1)
+        dq_ref[0, 0] = dq_scr[...]
 
 
 def _flash_attn_bwd_dkv_kernel(L_ref, q_ref, k_ref, v_ref, lse_ref,
@@ -253,6 +258,7 @@ def _flash_attn_bwd_dkv_kernel(L_ref, q_ref, k_ref, v_ref, lse_ref,
                                dv_scr, *, block_q: int, block_k: int, G: int,
                                scale: float, softcap: float, window: int,
                                causal: bool):
+    L0 = L_ref[pl.program_id(0)]
     j = pl.program_id(2)   # KV block
     i = pl.program_id(3)   # query block (innermost: accumulate dk/dv)
     q0 = i * block_q
@@ -263,17 +269,17 @@ def _flash_attn_bwd_dkv_kernel(L_ref, q_ref, k_ref, v_ref, lse_ref,
         dk_scr[...] = jnp.zeros_like(dk_scr)
         dv_scr[...] = jnp.zeros_like(dv_scr)
 
-    needed = _block_needed(L_ref[0], q0, k0, block_q=block_q,
+    needed = _block_needed(L0, q0, k0, block_q=block_q,
                            block_k=block_k, window=window, causal=causal)
 
     @pl.when(needed)
     def _accumulate():
-        p, ds, q2, _, do = _recompute_p_ds(
-            L_ref[0], q_ref, k_ref, v_ref, lse_ref, delta_ref, do_ref,
-            q0, k0, block_q=block_q, block_k=block_k, G=G, scale=scale,
+        p, ds, q, _, do = _recompute_p_ds(
+            L0, q_ref, k_ref, v_ref, lse_ref, delta_ref, do_ref,
+            q0, k0, block_k=block_k, G=G, scale=scale,
             softcap=softcap, window=window, causal=causal)
         dv_scr[...] += jnp.dot(p.T, do, preferred_element_type=jnp.float32)
-        dk_scr[...] += jnp.dot(ds.T, q2,
+        dk_scr[...] += jnp.dot(ds.T, q,
                                preferred_element_type=jnp.float32) * scale
 
     @pl.when(i == pl.num_programs(3) - 1)
@@ -282,49 +288,44 @@ def _flash_attn_bwd_dkv_kernel(L_ref, q_ref, k_ref, v_ref, lse_ref,
         dv_ref[0, 0] = dv_scr[...]
 
 
+def _bwd_kernel_args(st: Static, kernel, dh: int):
+    return functools.partial(
+        kernel, block_q=st.block_q, block_k=st.block_k, G=st.G,
+        scale=dh ** -0.5, softcap=float(st.softcap), window=int(st.window),
+        causal=bool(st.causal))
+
+
 def _bwd_dq_call(st: Static, q, k, v, L_arr, lse, delta, do):
-    B, KVH, S, G, dh = q.shape
+    B, KVH, SG, dh = q.shape
+    S = SG // st.G
     grid = (B, KVH, S // st.block_q, S // st.block_k)
-    kernel = functools.partial(
-        _flash_attn_bwd_dq_kernel, block_q=st.block_q, block_k=st.block_k,
-        G=G, scale=dh ** -0.5, softcap=float(st.softcap),
-        window=int(st.window), causal=bool(st.causal))
-    kv_spec = pl.BlockSpec((1, 1, st.block_k, dh),
-                           lambda b, h, i, j: (b, h, j, 0))
-    q_spec = pl.BlockSpec((1, 1, st.block_q, G, dh),
-                          lambda b, h, i, j: (b, h, i, 0, 0))
-    row_spec = pl.BlockSpec((1, 1, st.block_q, G),
-                            lambda b, h, i, j: (b, h, i, 0))
+    q_spec, kv_spec, row_spec = _row_specs(
+        st, dh, lambda b, h, i, j: (b, h, i, 0),
+        lambda b, h, i, j: (b, h, j, 0))
     return pl.pallas_call(
-        kernel,
+        _bwd_kernel_args(st, _flash_attn_bwd_dq_kernel, dh),
         grid=grid,
-        in_specs=[pl.BlockSpec((1,), lambda b, h, i, j: (b,)),
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),
                   q_spec, kv_spec, kv_spec, row_spec, row_spec, q_spec],
         out_specs=q_spec,
-        out_shape=jax.ShapeDtypeStruct((B, KVH, S, G, dh), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((st.block_q * G, dh), jnp.float32)],
+        out_shape=jax.ShapeDtypeStruct((B, KVH, SG, dh), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((st.block_q * st.G, dh), jnp.float32)],
         interpret=st.interpret,
     )(L_arr, q, k, v, lse, delta, do)
 
 
 def _bwd_dkv_call(st: Static, q, k, v, L_arr, lse, delta, do):
-    B, KVH, S, G, dh = q.shape
+    B, KVH, SG, dh = q.shape
+    S = SG // st.G
     # query axis innermost: each KV block accumulates over all query blocks
     grid = (B, KVH, S // st.block_k, S // st.block_q)
-    kernel = functools.partial(
-        _flash_attn_bwd_dkv_kernel, block_q=st.block_q, block_k=st.block_k,
-        G=G, scale=dh ** -0.5, softcap=float(st.softcap),
-        window=int(st.window), causal=bool(st.causal))
-    kv_spec = pl.BlockSpec((1, 1, st.block_k, dh),
-                           lambda b, h, j, i: (b, h, j, 0))
-    q_spec = pl.BlockSpec((1, 1, st.block_q, G, dh),
-                          lambda b, h, j, i: (b, h, i, 0, 0))
-    row_spec = pl.BlockSpec((1, 1, st.block_q, G),
-                            lambda b, h, j, i: (b, h, i, 0))
+    q_spec, kv_spec, row_spec = _row_specs(
+        st, dh, lambda b, h, j, i: (b, h, i, 0),
+        lambda b, h, j, i: (b, h, j, 0))
     return pl.pallas_call(
-        kernel,
+        _bwd_kernel_args(st, _flash_attn_bwd_dkv_kernel, dh),
         grid=grid,
-        in_specs=[pl.BlockSpec((1,), lambda b, h, j, i: (b,)),
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),
                   q_spec, kv_spec, kv_spec, row_spec, row_spec, q_spec],
         out_specs=[kv_spec, kv_spec],
         out_shape=[jax.ShapeDtypeStruct((B, KVH, S, dh), jnp.float32),
@@ -351,9 +352,9 @@ def _flash_attention_fwd(st: Static, q, k, v, L_arr):
 def _flash_attention_bwd(st: Static, res, do):
     q, k, v, L_arr, out, lse = res
     # delta = rowsum(dO * O): O(S*dh) elementwise work, done outside the
-    # kernels so both backward passes read it as a [B,KVH,S,G] stream
+    # kernels so both backward passes read it as a [B,KVH,S*G,1] stream
     delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
-                    axis=-1)
+                    axis=-1, keepdims=True)
     dq = _bwd_dq_call(st, q, k, v, L_arr, lse, delta, do)
     dk, dv = _bwd_dkv_call(st, q, k, v, L_arr, lse, delta, do)
     # integer lengths take a float0 cotangent (non-differentiable operand)
@@ -384,7 +385,10 @@ def flash_attention(q, k, v, lengths, *, block_q: int = 128,
     assert S % block_q == 0 and S % block_k == 0, (S, block_q, block_k)
     L_arr = jnp.broadcast_to(jnp.asarray(lengths, jnp.int32).reshape(-1),
                              (B,))
-    st = Static(block_q=int(block_q), block_k=int(block_k),
+    st = Static(G=G, block_q=int(block_q), block_k=int(block_k),
                 window=int(window), softcap=float(softcap),
                 causal=bool(causal), interpret=bool(interpret))
-    return _flash_attention(st, q, k, v, L_arr)
+    # G folds into the query rows here, a free reshape, so no kernel
+    # reshapes in-register (Mosaic refuses the backward's shape casts)
+    out = _flash_attention(st, q.reshape(B, KVH, S * G, dh), k, v, L_arr)
+    return out.reshape(B, KVH, S, G, dh)

@@ -1,8 +1,9 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: lower + compile every (arch x input-shape x mesh)
 combination on the production mesh and extract the roofline raw data.
+
+A host-device dry run: it pins ``JAX_PLATFORMS=cpu`` and forces 512 CPU
+devices before JAX starts, so it never takes an accelerator from another
+process (the production meshes are 256/512 host devices).
 
 For each combo we do up to three compiles:
 
@@ -21,6 +22,11 @@ Usage:
   python -m repro.launch.dryrun --arch qwen3-4b --shape train_4k --mesh single
   python -m repro.launch.dryrun --all --mesh both --out runs/dryrun
 """
+import os
+
+os.environ["JAX_PLATFORMS"] = "cpu"
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+
 import argparse
 import dataclasses
 import json

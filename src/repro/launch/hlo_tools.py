@@ -87,13 +87,9 @@ def op_totals(hlo_text: str, ops=OPS) -> dict:
 
 
 def cost_analysis(compiled) -> dict:
-    """Normalize ``compiled.cost_analysis()`` across jax versions: older
-    releases return a per-device list of dicts, newer ones a single dict
-    (or None when the backend offers no analysis)."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else None
-    return ca or {}
+    """``compiled.cost_analysis()`` as a dict (empty when the backend
+    offers no analysis)."""
+    return compiled.cost_analysis() or {}
 
 
 def main():

@@ -69,4 +69,10 @@ def make_mesh_from_config(mc: MeshConfig):
             f"need {mc.n_devices} devices for mesh {mc.shape}; have "
             f"{len(devices)}. Set XLA_FLAGS={host_device_flag(mc.n_devices)} "
             "before importing jax (see launch/dryrun.py).")
-    return jax.make_mesh(mc.shape, mc.axis_names, devices=devices)
+    # Auto axes: shardings are propagated by GSPMD from the committed
+    # inputs.  Left unset, jax.make_mesh builds Explicit axes, under which
+    # the round's sparse scatters raise ShardingTypeError.
+    return jax.make_mesh(mc.shape, mc.axis_names,
+                         axis_types=(jax.sharding.AxisType.Auto,)
+                         * len(mc.shape),
+                         devices=devices)

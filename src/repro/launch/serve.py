@@ -5,13 +5,21 @@ lower: per-request bucketed prefill into fixed-capacity decode slots, then
 compiled one-token decode steps over all active slots, with mid-decode
 admission and per-slot early exit (serving/engine.py).
 
-Example:
-  PYTHONPATH=src python -m repro.launch.serve --arch gemma2-27b --requests 6
+``--arch`` takes a registered arch at its published widths
+(``qwen2-1.5b``), its CPU-sized variant (``qwen2-1.5b-reduced``), or
+``tiny``.
+
+Examples:
+  PYTHONPATH=src python -m repro.launch.serve --arch gemma2-27b-reduced \\
+      --requests 6
+  PYTHONPATH=src python -m repro.launch.serve --arch qwen2-1.5b \\
+      --max-prompt 1100 --s-max 2048   # one TPU v5e
 """
 from __future__ import annotations
 
 import argparse
 import dataclasses
+import sys
 import time
 
 import jax
@@ -24,9 +32,13 @@ from repro.models.transformer import DEFAULT_CTX
 from repro.serving.engine import ContinuousBatchingEngine, ServeEngine
 
 
-def main():
+def main(argv=None):
+    """Serve ``--requests`` random prompts on ``argv`` (default
+    ``sys.argv[1:]``); returns the engine after it drained."""
     ap = argparse.ArgumentParser()
-    ap.add_argument("--arch", default="tiny")
+    ap.add_argument("--arch", default="tiny",
+                    help="tiny, a registered arch at its published widths, "
+                         "or <arch>-reduced for its CPU-sized variant")
     ap.add_argument("--engine", default="continuous",
                     choices=["continuous", "naive"])
     ap.add_argument("--backend", default="auto",
@@ -36,21 +48,25 @@ def main():
                     choices=["auto", "pallas", "online", "dense"],
                     help="prefill forward-attention route")
     ap.add_argument("--requests", type=int, default=6)
+    ap.add_argument("--max-prompt", type=int, default=24,
+                    help="prompt lengths are drawn from [4, max-prompt)")
     ap.add_argument("--max-new", type=int, default=12)
     ap.add_argument("--max-batch", type=int, default=4,
                     help="decode slots (continuous) / batch size (naive)")
     ap.add_argument("--s-max", type=int, default=128)
     ap.add_argument("--seed", type=int, default=0)
-    a = ap.parse_args()
+    a = ap.parse_args(sys.argv[1:] if argv is None else argv)
 
-    cfg = TINY if a.arch == "tiny" else get_config(a.arch).reduced()
+    cfg = TINY if a.arch == "tiny" else get_config(a.arch)
     ctx = dataclasses.replace(DEFAULT_CTX, attn_backend=a.attn_backend)
     model = Model(cfg, ctx=ctx)
     params = model.init(jax.random.key(a.seed))
-    print(f"arch={cfg.name} params={model.n_params:,} engine={a.engine}")
+    print(f"arch={cfg.name} params={model.n_params:,} ({cfg.dtype}) "
+          f"engine={a.engine}")
 
     rng = np.random.default_rng(a.seed)
-    prompts = [rng.integers(0, cfg.vocab, size=int(rng.integers(4, 24)))
+    prompts = [rng.integers(0, cfg.vocab,
+                            size=int(rng.integers(4, a.max_prompt)))
                for _ in range(a.requests)]
     t0 = time.time()
     if a.engine == "continuous":
@@ -75,7 +91,10 @@ def main():
              f"compiles={stats['compile_misses']}" if stats else "")
     print(f"{n_tok} tokens in {dt:.1f}s ({n_tok / dt:.1f} tok/s,"
           f" {a.engine} batching with cache{extra})")
+    return engine
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

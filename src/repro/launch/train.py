@@ -1,8 +1,10 @@
-"""Federated MEERKAT training driver (runnable end-to-end on CPU).
+"""Federated MEERKAT training driver.
 
-Runs sparse-ZO federated fine-tuning of any registered architecture's
-*reduced* variant (or the tiny model) on the synthetic classification-LM
-task family with Dirichlet Non-IID clients — Algorithm 2 end to end:
+Runs sparse-ZO federated fine-tuning of any registered architecture at its
+published widths (``--arch qwen2-1.5b``), of its CPU-sized variant
+(``--arch qwen2-1.5b-reduced``), or of the tiny model, on the synthetic
+classification-LM task family with Dirichlet Non-IID clients — Algorithm 2
+end to end:
 mask calibration from the C4-proxy corpus, per-round seed ladders, client
 local ZO steps, server virtual-path reconstruction and aggregation, and
 optional MEERKAT-VP calibration + early stopping.
@@ -11,12 +13,15 @@ optional MEERKAT-VP calibration + early stopping.
 (``sharding/fl.FLShardPlan``): parameters per ``sharding/rules.py``
 (``--mesh-rule``, FSDP by default), the client axis over the mesh batch
 axes.  On a CPU host the requested device count is forced via XLA_FLAGS
-*before* jax is imported (pre-parsed from argv below); on TPU the same
-spec maps onto the physical topology.
+before the backend starts (pre-parsed from argv in :func:`main`); on TPU
+the same spec maps onto the physical topology.
 
 Examples:
   PYTHONPATH=src python -m repro.launch.train --rounds 40 --T 10
-  PYTHONPATH=src python -m repro.launch.train --arch qwen3-4b --method full
+  PYTHONPATH=src python -m repro.launch.train --arch qwen3-4b-reduced \\
+      --method full
+  PYTHONPATH=src python -m repro.launch.train --arch qwen2-1.5b \\
+      --density 1e-3 --clients 4 --rounds 3 --T 2   # one TPU v5e
   PYTHONPATH=src python -m repro.launch.train --vp --partition mixed
   PYTHONPATH=src python -m repro.launch.train --mesh 2x2 --rounds 4
   PYTHONPATH=src python -m repro.launch.train --checkpoint-dir runs/ckpt \\
@@ -32,37 +37,8 @@ import os
 import sys
 import time
 
-
-def _force_mesh_devices(argv):
-    """If --mesh asks for more devices than the host platform exposes,
-    force the count via XLA_FLAGS.  Runs before the first jax import —
-    device count is fixed at backend initialization.  (Importing
-    launch.mesh here is safe: it touches no jax device state.)"""
-    spec = None
-    for i, a in enumerate(argv):
-        if a == "--mesh" and i + 1 < len(argv):
-            spec = argv[i + 1]
-        elif a.startswith("--mesh="):
-            spec = a.split("=", 1)[1]
-    if not spec:
-        return
-    if "--xla_force_host_platform_device_count" in \
-            os.environ.get("XLA_FLAGS", ""):
-        return
-    from repro.launch.mesh import host_device_flag, parse_mesh_spec
-    try:
-        n = parse_mesh_spec(spec).n_devices
-    except ValueError:
-        return  # argparse will reject the spec with a proper error
-    if n > 1:
-        os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
-                                   + " " + host_device_flag(n)).strip()
-
-
-_force_mesh_devices(sys.argv[1:])
-
-import jax  # noqa: E402  (after the XLA_FLAGS pre-parse, by design)
-import numpy as np  # noqa: E402
+import jax
+import numpy as np
 
 from repro.checkpoint.state import FINAL_NAME, LATEST_NAME
 from repro.configs import get_config
@@ -75,8 +51,34 @@ from repro.data.corpus import pretrain_batches
 from repro.data.partition import (dirichlet_partition, iid_partition,
                                   single_label_partition, subset)
 from repro.data.synthetic import TaskSpec, make_task_fns, sample_dataset
+from repro.launch.mesh import host_device_flag, parse_mesh_spec
 from repro.models import Model
 from repro.models.transformer import DEFAULT_CTX
+
+
+def _force_mesh_devices(argv):
+    """If --mesh asks for more devices than the host platform exposes,
+    force the count via XLA_FLAGS.  Runs before the first device query —
+    the count is fixed when the backend initializes (importing jax does
+    not initialize it)."""
+    spec = None
+    for i, a in enumerate(argv):
+        if a == "--mesh" and i + 1 < len(argv):
+            spec = argv[i + 1]
+        elif a.startswith("--mesh="):
+            spec = a.split("=", 1)[1]
+    if not spec:
+        return
+    if "--xla_force_host_platform_device_count" in \
+            os.environ.get("XLA_FLAGS", ""):
+        return
+    try:
+        n = parse_mesh_spec(spec).n_devices
+    except ValueError:
+        return  # argparse will reject the spec with a proper error
+    if n > 1:
+        os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
+                                   + " " + host_device_flag(n)).strip()
 
 
 def build_space(method, loss_fn, params, pre, density, seed):
@@ -93,10 +95,15 @@ def build_space(method, loss_fn, params, pre, density, seed):
     raise ValueError(method)
 
 
-def main():
+def main(argv=None):
+    """Run the driver on ``argv`` (default ``sys.argv[1:]``); returns the
+    :class:`FederatedZO` server after its last round."""
+    argv = sys.argv[1:] if argv is None else list(argv)
+    _force_mesh_devices(argv)
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="tiny",
-                    help="tiny or any registered arch (reduced variant used)")
+                    help="tiny, a registered arch at its published widths, "
+                         "or <arch>-reduced for its CPU-sized variant")
     ap.add_argument("--method", default="meerkat",
                     choices=["meerkat", "magnitude", "random", "full", "lora"])
     ap.add_argument("--partition", default="dirichlet",
@@ -162,9 +169,9 @@ def main():
                              "int4-nearest"],
                     help="uplink codec for the ZO scalars "
                          "(core/quantize.py exact-replay quantizer)")
-    a = ap.parse_args()
+    a = ap.parse_args(argv)
 
-    cfg = TINY if a.arch == "tiny" else get_config(a.arch).reduced()
+    cfg = TINY if a.arch == "tiny" else get_config(a.arch)
     if a.method == "lora" and cfg.lora_rank == 0:
         cfg = cfg.replace(lora_rank=4)
     spec = TaskSpec(vocab=min(cfg.vocab, 512), seq_len=16)
@@ -176,7 +183,8 @@ def main():
         print(f"mesh: {a.mesh} ({plan.mesh_cfg.n_devices} devices, "
               f"rule={a.mesh_rule}, client axis over {plan.batch_axes})")
     model = Model(cfg, ctx=ctx)
-    print(f"arch={cfg.name} params={model.n_params:,} method={a.method}")
+    print(f"arch={cfg.name} params={model.n_params:,} ({cfg.dtype}) "
+          f"method={a.method}")
 
     params = model.init(jax.random.key(a.seed))
     loss, per_example, evaluate = make_task_fns(model, spec)
@@ -213,8 +221,14 @@ def main():
                   vp_sigma=0.25, vp_sigma_relative=True,
                   sample_frac=a.sample_frac,
                   sample_weighted=a.sample_weighted, quantize=a.quantize)
+    gp = None
+    if a.vp and not a.resume:
+        # (resume restores the calibrated VPCS flags and the consumed data
+        # pointers; recalibrating would reset both and break bit-exactness)
+        gp = pretrain_gradient_vec(lm_loss_fn, params, space, pre)
     server = FederatedZO(loss, params, space, fl, clients, eval_fn=evaluate,
                          plan=plan)
+    del params  # the server holds the weights (placed on the mesh if any)
     if server.sampler is not None or server.codec.spec != "none":
         m = "full" if server.sampler is None else server.sampler.m
         print(f"fleet: cohort {m}/{a.clients} per round"
@@ -231,26 +245,20 @@ def main():
                                seed=a.fault_seed, kill_rounds=kills)
         print("faults:", fault_plan.summary())
 
-    resumed = False
     if a.resume:
         if not a.checkpoint_dir:
             ap.error("--resume requires --checkpoint-dir")
         latest = os.path.join(a.checkpoint_dir, LATEST_NAME)
         server.load_checkpoint(latest)
-        resumed = True
         print(f"resumed from {latest} at round {server.round}")
 
-    if a.vp and not resumed:
-        # (resume restores the calibrated VPCS flags and the consumed data
-        # pointers; recalibrating would reset both and break bit-exactness)
-        gp = pretrain_gradient_vec(lm_loss_fn, params, space, pre)
+    if gp is not None:
         results, flagged, _ = server.calibrate_vp(gp)
         print(f"VPCS flagged clients {flagged} "
               f"(rho_later={[round(r.rho_later, 2) for r in results]})")
 
-    m0 = evaluate(server.params, eval_batch)
-    print(f"round {server.round}: acc={float(m0['acc']):.4f} "
-          f"loss={float(m0['loss']):.4f}")
+    m0 = server.evaluate(eval_batch)
+    print(f"round {server.round}: acc={m0['acc']:.4f} loss={m0['loss']:.4f}")
     server.run(max(0, a.rounds - server.round), eval_every=a.eval_every,
                eval_batch=eval_batch, verbose=True, fault_plan=fault_plan,
                checkpoint_dir=a.checkpoint_dir,
@@ -259,18 +267,20 @@ def main():
         final = server.save_checkpoint(os.path.join(a.checkpoint_dir,
                                                     FINAL_NAME))
         print("wrote", final)
-    m = evaluate(server.params, eval_batch)
-    print(f"final: acc={float(m['acc']):.4f} loss={float(m['loss']):.4f} "
+    m = server.evaluate(eval_batch)
+    print(f"final: acc={m['acc']:.4f} loss={m['loss']:.4f} "
           f"({time.time() - t0:.0f}s total)  comm: up={server.comm.up_bytes}B "
           f"down={server.comm.down_bytes}B")
     if a.out:
         os.makedirs(os.path.dirname(a.out) or ".", exist_ok=True)
         with open(a.out, "w") as f:
-            json.dump({"history": server.history,
-                       "final": {k: float(v) for k, v in m.items()},
+            json.dump({"history": server.history, "final": m,
                        "args": vars(a)}, f, indent=1)
         print("wrote", a.out)
+    return server
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

@@ -25,8 +25,12 @@ def _norm_p(cfg, d, n=None, kind=None):
 
 
 class _KeyGen:
-    def __init__(self, key):
+    """Split-on-call key stream; ``dtype`` is the dtype weights are
+    created in, cast leaf by leaf so no full-model f32 copy is ever held."""
+
+    def __init__(self, key, dtype):
         self.key = key
+        self.dtype = dtype
 
     def __call__(self):
         self.key, k = jax.random.split(self.key)
@@ -35,7 +39,7 @@ class _KeyGen:
 
 def _dense(kg, shape, std=0.02, n=None):
     shape = (n, *shape) if n else shape
-    return jax.random.normal(kg(), shape) * std
+    return (jax.random.normal(kg(), shape) * std).astype(kg.dtype)
 
 
 def _attn_params(kg, cfg: ModelConfig, n: int, cross: bool = False):
@@ -193,9 +197,11 @@ def _stack_params(kg, cfg: ModelConfig, pattern, n_periods: int,
     return stack
 
 
-def init_params(key, cfg: ModelConfig, dtype=jnp.float32):
-    """Initialize the full parameter pytree for ``cfg``."""
-    kg = _KeyGen(key)
+def init_params(key, cfg: ModelConfig, dtype=None):
+    """Initialize the full parameter pytree for ``cfg`` in ``dtype``
+    (default: the configuration's own ``cfg.dtype``)."""
+    dtype = jnp.dtype(dtype or cfg.dtype)
+    kg = _KeyGen(key, dtype)
     params = {
         "embed": _dense(kg, (cfg.vocab, cfg.d_model)),
         "stack": _stack_params(kg, cfg, cfg.layer_pattern, cfg.n_periods,
@@ -213,7 +219,7 @@ def init_params(key, cfg: ModelConfig, dtype=jnp.float32):
     return jax.tree.map(lambda a: a.astype(dtype), params)
 
 
-def abstract_params(cfg: ModelConfig, dtype=jnp.bfloat16):
+def abstract_params(cfg: ModelConfig, dtype=None):
     """ShapeDtypeStruct pytree (no allocation) — used by the dry-run."""
     return jax.eval_shape(
         lambda: init_params(jax.random.key(0), cfg, dtype=dtype))

@@ -20,10 +20,11 @@ class Model:
         self.cfg = cfg
         self.ctx = ctx
 
-    def init(self, key, dtype=jnp.float32):
+    def init(self, key, dtype=None):
+        """Random weights in ``dtype`` (default ``cfg.dtype``)."""
         return init_params(key, self.cfg, dtype=dtype)
 
-    def abstract_params(self, dtype=jnp.bfloat16):
+    def abstract_params(self, dtype=None):
         return abstract_params(self.cfg, dtype=dtype)
 
     def forward(self, params, batch):

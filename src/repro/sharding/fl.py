@@ -137,19 +137,18 @@ class FLShardPlan:
 
         ``template_batches``: the stacked batch dict (for leaf ranks);
         ``out_ndims``: ranks of the (deltas, gs) outputs."""
-        from jax.experimental.shard_map import shard_map
         k_spec = self.batch_axes if n_clients % self.dp == 0 else None
 
         def kspec(ndim):
             return P(k_spec, *([None] * (ndim - 1)))
 
-        return shard_map(
+        return jax.shard_map(
             body, mesh=self.mesh,
             in_specs=(P(), P(None),
                       {k: kspec(v.ndim)
                        for k, v in template_batches.items()}),
             out_specs=tuple(kspec(nd) for nd in out_ndims),
-            check_rep=False)
+            check_vma=False)
 
     def compute_view(self, params):
         """The in-graph view of the (sharded-at-rest) parameters that the

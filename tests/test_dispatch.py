@@ -130,6 +130,28 @@ def test_auto_prefers_pallas_and_falls_back():
         resolve_backend("cuda", b)
 
 
+def test_auto_budget_follows_device_reported_memory(monkeypatch):
+    from repro.core import dispatch
+    params = vec_params(jax.random.key(6))
+    space = random_mask(params, density=0.5, seed=0)
+    b = get_backing(space, params)
+    need = dispatch.FLAT_ROUTE_BYTES_PER_COORD * b.n_pad
+
+    class Device:  # what a TPU reports: limit and bytes already in use
+        def __init__(self, free):
+            self.free = free
+
+        def memory_stats(self):
+            return {"bytes_limit": self.free + 1000, "bytes_in_use": 1000}
+
+    monkeypatch.setattr(dispatch.jax, "devices", lambda: [Device(need)])
+    assert resolve_backend("auto", b) == "pallas"
+    assert resolve_backend("auto", b, dense_carry=2) == "ref"
+    assert resolve_backend("pallas", b, dense_carry=2) == "pallas"
+    monkeypatch.setattr(dispatch.jax, "devices", lambda: [Device(need - 1)])
+    assert resolve_backend("auto", b) == "ref"
+
+
 def test_auto_falls_back_on_mixed_dtypes():
     params = {"a": jnp.ones((8,), jnp.float32),
               "b": jnp.ones((8,), jnp.bfloat16)}

@@ -30,6 +30,9 @@ pinned on every run: mesh routes resolve to the pytree backend, and
 bit-comparison across topologies needs both sides on the same route
 (DESIGN.md §9).
 
+A CPU harness: the three runs get ``JAX_PLATFORMS=cpu`` unless the
+caller set it, so they never contend for an accelerator.
+
 CI runs::
 
     PYTHONPATH=src python tools/kill_recover.py --rounds 4 --kill-at 2
