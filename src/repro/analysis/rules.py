@@ -162,8 +162,9 @@ class RecompileHazardRule(Rule):
     2. (warning) scalar int literals equal to a declared dynamic dim —
        a baked ``S``/bucket width that will fork the compile cache.
     3. (error) the trace-count harness: call the built fn twice with the
-       same concrete args under ``jax_log_compiles`` — any XLA compile on
-       the second call means steady-state serving/training re-traces.
+       same concrete args — any XLA compile on the second call (counted
+       from ``repro.obs``'s compile records) means steady-state
+       serving/training re-traces.
     """
 
     name = "recompile-hazard"
@@ -209,32 +210,13 @@ class RecompileHazardRule(Rule):
 
     @staticmethod
     def _second_call_compiles(built: Built) -> int:
-        import logging
-
         import jax
+
+        from repro import obs
         jax.block_until_ready(built.fn(*built.args))   # warm-up call
-        events = []
-
-        class _Counter(logging.Handler):
-            def emit(self, record):
-                if "Finished XLA compilation" in record.getMessage():
-                    events.append(record.getMessage())
-
-        logger = logging.getLogger("jax._src.dispatch")
-        pxla = logging.getLogger("jax._src.interpreters.pxla")
-        handler = _Counter(logging.DEBUG)
-        old_propagate = (logger.propagate, pxla.propagate)
-        old_flag = jax.config.jax_log_compiles
-        logger.addHandler(handler)
-        logger.propagate = pxla.propagate = False    # count quietly
-        jax.config.update("jax_log_compiles", True)
-        try:
-            jax.block_until_ready(built.fn(*built.args))
-        finally:
-            jax.config.update("jax_log_compiles", old_flag)
-            logger.removeHandler(handler)
-            logger.propagate, pxla.propagate = old_propagate
-        return len(events)
+        before = obs.compile_count()
+        jax.block_until_ready(built.fn(*built.args))
+        return obs.compile_count() - before
 
 
 class CommBudgetRule(Rule):

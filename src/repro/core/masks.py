@@ -14,6 +14,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core.spaces import MaskedSpace
 
 
@@ -59,31 +60,38 @@ def _global_topk_indices(score_tree, density: float):
     Exactly k indices: every score above the k-th largest, then the
     lowest-index ties at it.  The selection runs on the host over one
     f32 copy of the scores plus one partition buffer (no int64 index
-    array of the full model)."""
+    array of the full model).  Spans: ``mask.to_host`` (the copy, which
+    waits for the scores), ``mask.topk`` (the selection) and
+    ``mask.to_device`` (the index leaves)."""
     leaves, treedef = jax.tree_util.tree_flatten(score_tree)
     sizes = [int(np.prod(l.shape)) for l in leaves]
     offsets = np.concatenate([[0], np.cumsum(sizes)])
     total = int(offsets[-1])
     k = _n_select(total, density)
-    flat = np.empty((total,), np.float32)
-    for l, o, s in zip(leaves, offsets[:-1], sizes):
-        flat[o:o + s] = np.asarray(l, np.float32).ravel()
-    kth = np.partition(flat, total - k)[total - k]
-    top = np.flatnonzero(flat > kth)
-    ties = np.flatnonzero(flat == kth)[:k - top.size]
-    top = np.sort(np.concatenate([top, ties]))
-    del flat
-    idx_leaves = []
-    for i in range(len(leaves)):
-        lo, hi = np.searchsorted(top, [offsets[i], offsets[i + 1]])
-        idx_leaves.append(jnp.asarray(top[lo:hi] - offsets[i], jnp.int32))
+    with obs.span("mask.to_host"):
+        flat = np.empty((total,), np.float32)
+        for l, o, s in zip(leaves, offsets[:-1], sizes):
+            flat[o:o + s] = np.asarray(l, np.float32).ravel()
+    with obs.span("mask.topk"):
+        kth = np.partition(flat, total - k)[total - k]
+        top = np.flatnonzero(flat > kth)
+        ties = np.flatnonzero(flat == kth)[:k - top.size]
+        top = np.sort(np.concatenate([top, ties]))
+        del flat
+        bounds = [np.searchsorted(top, [offsets[i], offsets[i + 1]])
+                  for i in range(len(leaves))]
+    with obs.span("mask.to_device"):
+        idx_leaves = [jnp.asarray(top[lo:hi] - offsets[i], jnp.int32)
+                      for i, (lo, hi) in enumerate(bounds)]
     return jax.tree_util.tree_unflatten(treedef, idx_leaves)
 
 
 def sensitivity_mask(loss_fn, params, pretrain_batches, density: float
                      ) -> MaskedSpace:
-    """MEERKAT's mask: global top-u by avg squared pre-training gradient."""
-    scores = sensitivity_scores(loss_fn, params, pretrain_batches)
+    """MEERKAT's mask: global top-u by avg squared pre-training gradient.
+    ``mask.scores`` spans the dispatch of the gradient accumulation."""
+    with obs.span("mask.scores"):
+        scores = sensitivity_scores(loss_fn, params, pretrain_batches)
     return MaskedSpace(_global_topk_indices(scores, density))
 
 

@@ -46,6 +46,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.configs.base import FLConfig
 from repro.core import seeds as S
 from repro.core import virtual_path as VP
@@ -169,16 +170,21 @@ class FederatedZO:
         self._pending: List[dict] = []
         self.last_round_info: Optional[dict] = None
         self._batch_runs: Dict[tuple, Callable] = {}
+        # (T, group width) -> abstract (shape, dtype, sharding) arguments
+        # of that group program's first call (``group_hlo_text``)
+        self._group_args: Dict[tuple, tuple] = {}
         # (T, group width) -> the ZO route ("pallas" | "ref") its program
         # runs, and the host seconds of each round ``run`` completed
         self.zo_routes: Dict[tuple, str] = {}
         self.round_seconds: List[float] = []
         # per-client GradIP [T_cali] of the last calibrate_vp call
         self.vp_trajectories: Optional[List[np.ndarray]] = None
-        self._recon = jax.jit(
-            lambda keys, gs: jax.vmap(
-                lambda g: VP.reconstruct_delta(self.space, keys, g,
-                                               self.fl.lr))(gs))
+
+        def replay(keys, gs):
+            return jax.vmap(lambda g: VP.reconstruct_delta(
+                self.space, keys, g, self.fl.lr))(gs)
+
+        self._recon = jax.jit(replay)
 
     # -- jitted vmapped T-step client group (one compile per distinct
     # (T, group width); the width feeds the auto backend's dense-carry
@@ -199,6 +205,7 @@ class FederatedZO:
         (``compute_view``) — allclose-level parity only."""
         key = (T, n_group)
         if key not in self._batch_runs:
+            obs.count("fl.programs_built")
             # resolve the ZO route here, once per group program, so the
             # route each group took is observable (``zo_routes``)
             route = resolve_backend(
@@ -255,6 +262,29 @@ class FederatedZO:
         return (self.plan.place_replicated(keys),
                 self.plan.place_client_batches(batches, n_group))
 
+    def _call_group(self, key, grp, keys_d, batches):
+        """Dispatch a group program; the abstract arguments of its first
+        call (a committed argument's sharding too) are kept for
+        :meth:`group_hlo_text`."""
+        if key not in self._group_args:
+            self._group_args[key] = jax.tree.map(
+                lambda x: jax.ShapeDtypeStruct(
+                    x.shape, x.dtype, sharding=x.sharding
+                    if getattr(x, "committed", False) else None),
+                (self.params, keys_d, batches))
+        return grp(self.params, keys_d, batches)
+
+    def group_hlo_text(self, T: int, width: int) -> str:
+        """Optimized HLO text of the (T, width) client group program as the
+        device runs it: lowered with the abstract arguments of its first
+        call, which finds the executable that call compiled.  Instructions
+        carry ``core/zo.py``'s ``zo.*`` scopes in their ``op_name``
+        metadata, so a trace's op names join to them.  Call it outside
+        timed work: where the executable is not found, it compiles."""
+        key = (T, width)
+        return self._batch_runs[key].lower(
+            *self._group_args[key]).compile().as_text()
+
     # -- one federated round (Alg. 2 + the failure model) --------------------
     def run_round(self, gp_vec=None, faults=None):
         """Execute one round: group clients by local-step count T, run each
@@ -298,12 +328,22 @@ class FederatedZO:
         survivor-count-aware :func:`VP.aggregate`; a zero-reporter round
         applies a zero update.  Diagnostics land in
         ``self.last_round_info``."""
+        up, down = self.comm.up_bytes, self.comm.down_bytes
+        with obs.span("fl.round", round=self.round) as sp:
+            gs_by_cid = self._round(gp_vec, faults)
+            sp.attrs.update(up_bytes=self.comm.up_bytes - up,
+                            down_bytes=self.comm.down_bytes - down)
+        return gs_by_cid
+
+    def _round(self, gp_vec, faults):
+        """:meth:`run_round`'s body, its phases in ``fl.*`` spans."""
         from repro.fault.plan import NO_FAULTS
         f = faults if faults is not None else NO_FAULTS
         r = self.round
-        cohort = self._cohort(r)
-        in_cohort = set(cohort)
-        f = f.restrict(in_cohort)
+        with obs.span("fl.inputs"):
+            cohort = self._cohort(r)
+            in_cohort = set(cohort)
+            f = f.restrict(in_cohort)
         if gp_vec is not None:
             for c in self.clients:
                 if c.cid not in in_cohort:
@@ -328,12 +368,17 @@ class FederatedZO:
             cs = [c for c in groups[T] if c.cid not in f.drops]
             if not cs:
                 continue
-            keys = S.round_keys(self.fl.seed, r, T)
-            batches = self._stack([c.next_batches(T) for c in cs])
-            grp = self._batch_run_for(T, len(cs), template_batches=batches)
-            keys_d, batches = self._place_group(keys, batches, len(cs))
+            with obs.span("fl.inputs"):
+                keys = S.round_keys(self.fl.seed, r, T)
+                batches = self._stack([c.next_batches(T) for c in cs])
+                grp = self._batch_run_for(T, len(cs),
+                                          template_batches=batches)
+                keys_d, batches = self._place_group(keys, batches, len(cs))
             # (1) clients run T local ZO steps; upload the scalars g_k^{1..T}
-            _, gs = grp(self.params, keys_d, batches)
+            with obs.span("fl.group"):
+                _, gs = self._call_group((T, len(cs)), grp, keys_d, batches)
+            with obs.span("fl.group_wait"):
+                gs = np.asarray(gs)
             # (2) server reconstructs each client's virtual path from
             #     (seed list, scalars) — no data, no dense vectors.  The
             #     scalars are gathered to host first so replay/aggregation
@@ -343,35 +388,39 @@ class FederatedZO:
             # bills and replays (identical to the client's applied
             # values — exact-replay quantization), and the billed bytes
             # are the encoded wire size
-            wires = [self.codec.encode(g) for g in np.asarray(gs)]
-            gs = np.stack([self.codec.decode(w) for w in wires])
+            with obs.span("fl.uplink"):
+                wires = [self.codec.encode(g) for g in gs]
+                gs = np.stack([self.codec.decode(w) for w in wires])
             prompt = [i for i, c in enumerate(cs) if c.cid not in f.late]
             if prompt:
-                deltas.append(np.asarray(self._recon(
-                    keys, jnp.asarray(gs[np.asarray(prompt)]))))
-            for i, c in enumerate(cs):
-                g = gs[i]
-                if c.cid in f.late:
-                    # straggler: the downlink happened (it participated),
-                    # the upload is in flight until its arrival round
-                    self.comm.add(up=0, down=self._down_bytes(T))
-                    gip_idx = -1
+                with obs.span("fl.replay"):
+                    deltas.append(np.asarray(self._recon(
+                        keys, jnp.asarray(gs[np.asarray(prompt)]))))
+            with obs.span("fl.uplink"):
+                for i, c in enumerate(cs):
+                    g = gs[i]
+                    if c.cid in f.late:
+                        # straggler: the downlink happened (it participated),
+                        # the upload is in flight until its arrival round
+                        self.comm.add(up=0, down=self._down_bytes(T))
+                        gip_idx = -1
+                        if gp_vec is not None:
+                            self.gradip_log[c.cid].append(None)
+                            gip_idx = len(self.gradip_log[c.cid]) - 1
+                        self._pending.append(dict(
+                            arrive=r + int(f.late[c.cid]), cid=c.cid,
+                            src_round=r, gip_idx=gip_idx, gs=g))
+                        continue
+                    gs_by_cid[c.cid] = g
+                    # upload = every projected-gradient scalar block (T with
+                    # n_dirs=1, T*K multi-direction) at the codec's wire size
+                    self.comm.add(up=wires[i].nbytes, down=self._down_bytes(T))
                     if gp_vec is not None:
-                        self.gradip_log[c.cid].append(None)
-                        gip_idx = len(self.gradip_log[c.cid]) - 1
-                    self._pending.append(dict(
-                        arrive=r + int(f.late[c.cid]), cid=c.cid,
-                        src_round=r, gip_idx=gip_idx, gs=g))
-                    continue
-                gs_by_cid[c.cid] = g
-                # upload = every projected-gradient scalar block (T with
-                # n_dirs=1, T*K multi-direction) at the codec's wire size
-                self.comm.add(up=wires[i].nbytes, down=self._down_bytes(T))
-                if gp_vec is not None:
-                    ips, _, _ = gradip_trajectory(self.space, keys,
-                                                  jnp.asarray(_per_step(g)),
-                                                  gp_vec)
-                    self.gradip_log[c.cid].append(np.asarray(ips))
+                        with obs.span("fl.gradip"):
+                            ips, _, _ = gradip_trajectory(
+                                self.space, keys, jnp.asarray(_per_step(g)),
+                                gp_vec)
+                            self.gradip_log[c.cid].append(np.asarray(ips))
         # (2b) stragglers landing this round: replay their virtual path with
         # the *source* round's seed keys — exact, because the seed ladder is
         # a pure function of (fl.seed, round, T); fill the GradIP gap logged
@@ -383,35 +432,39 @@ class FederatedZO:
             gs_l = np.asarray(p["gs"])
             src_keys = S.round_keys(self.fl.seed, p["src_round"],
                                     gs_l.shape[0])
-            deltas.append(np.asarray(self._recon(src_keys,
-                                                 jnp.asarray(gs_l[None]))))
+            with obs.span("fl.replay"):
+                deltas.append(np.asarray(self._recon(
+                    src_keys, jnp.asarray(gs_l[None]))))
             self.comm.add(up=self.codec.nbytes(gs_l.size), down=0)
             if gp_vec is not None and p["gip_idx"] >= 0:
-                ips, _, _ = gradip_trajectory(self.space, src_keys,
-                                              jnp.asarray(_per_step(gs_l)),
-                                              gp_vec)
-                self.gradip_log[p["cid"]][p["gip_idx"]] = np.asarray(ips)
+                with obs.span("fl.gradip"):
+                    ips, _, _ = gradip_trajectory(
+                        self.space, src_keys, jnp.asarray(_per_step(gs_l)),
+                        gp_vec)
+                    self.gradip_log[p["cid"]][p["gip_idx"]] = np.asarray(ips)
             arrived.append((p["cid"], p["src_round"], gs_l))
         if f.kill:
             from repro.fault import plan as _fault_plan
             _fault_plan.kill_now()  # mid-round: work done, update not applied
         # (3) aggregate the reconstructed sparse updates of whoever reported
         # (+ optional FedAvgM server momentum — beyond-paper)
-        n_report = sum(int(d.shape[0]) for d in deltas)
-        if n_report:
-            agg = VP.aggregate(
-                jnp.concatenate([jnp.asarray(d) for d in deltas], axis=0),
-                n_report)
-        else:  # zero-survivor round: well-defined no-op update
-            agg = jnp.zeros((self.space.n,), jnp.float32)
-        if self.fl.server_momentum > 0.0:
-            self.velocity = (agg if self.velocity is None
-                             else self.fl.server_momentum * self.velocity
-                             + agg)
-            agg = self.velocity
-        if self.plan is not None:
-            agg = self.plan.place_replicated(agg)
-        self.params = self.space.add(self.params, agg)
+        with obs.span("fl.aggregate"):
+            n_report = sum(int(d.shape[0]) for d in deltas)
+            if n_report:
+                agg = VP.aggregate(
+                    jnp.concatenate([jnp.asarray(d) for d in deltas], axis=0),
+                    n_report)
+            else:  # zero-survivor round: well-defined no-op update
+                agg = jnp.zeros((self.space.n,), jnp.float32)
+            if self.fl.server_momentum > 0.0:
+                self.velocity = (agg if self.velocity is None
+                                 else self.fl.server_momentum * self.velocity
+                                 + agg)
+                agg = self.velocity
+            if self.plan is not None:
+                agg = self.plan.place_replicated(agg)
+        with obs.span("fl.update"):
+            self.params = self.space.add(self.params, agg)
         self.round += 1
         self.last_round_info = dict(
             round=r, n_reporting=n_report, drops=sorted(f.drops),
@@ -440,23 +493,24 @@ class FederatedZO:
         ``fl.vp_calibration_steps``).  Returns (results
         [:class:`repro.core.vpcs.VPCSResult` per client], flagged client
         id list, trajectories [list of GradIP [T_cali] arrays])."""
-        T = T_cali or self.fl.vp_calibration_steps
-        keys = S.round_keys(self.fl.seed, -1, T)
-        batches = self._stack([c.next_batches(T) for c in self.clients])
-        grp = self._batch_run_for(T, len(self.clients),
-                                  template_batches=batches)
-        keys_d, batches = self._place_group(keys, batches, len(self.clients))
-        _, gs = grp(self.params, keys_d, batches)
-        trajs = []
-        for c, g in zip(self.clients, np.asarray(gs)):
-            ips, _, _ = gradip_trajectory(self.space, keys,
-                                          jnp.asarray(_per_step(g)), gp_vec)
-            trajs.append(np.asarray(ips))
-            c.ptr = 0  # calibration does not consume training order
-        results, flagged = VPCS.select_clients(trajs, self.fl)
-        self.early_stopped = set(flagged)
-        self.vp_trajectories = trajs
-        return results, flagged, trajs
+        with obs.span("fl.vp_calibration"):
+            T = T_cali or self.fl.vp_calibration_steps
+            keys = S.round_keys(self.fl.seed, -1, T)
+            batches = self._stack([c.next_batches(T) for c in self.clients])
+            width = len(self.clients)
+            grp = self._batch_run_for(T, width, template_batches=batches)
+            keys_d, batches = self._place_group(keys, batches, width)
+            _, gs = self._call_group((T, width), grp, keys_d, batches)
+            trajs = []
+            for c, g in zip(self.clients, np.asarray(gs)):
+                ips, _, _ = gradip_trajectory(
+                    self.space, keys, jnp.asarray(_per_step(g)), gp_vec)
+                trajs.append(np.asarray(ips))
+                c.ptr = 0  # calibration does not consume training order
+            results, flagged = VPCS.select_clients(trajs, self.fl)
+            self.early_stopped = set(flagged)
+            self.vp_trajectories = trajs
+            return results, flagged, trajs
 
     def early_stop_random(self, n: int, seed: int = 0):
         """Random-client-selection baseline: early-stop n random clients."""

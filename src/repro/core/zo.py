@@ -18,6 +18,12 @@ Every entry point dispatches between two execution routes (see
 * ``backend="ref"``    — the original ``space.add`` pytree route (reference
   semantics; required for sharded weights and odd layouts).
 * ``backend=None``/"auto" picks pallas whenever the flat layout supports it.
+
+Both routes name their phases with ``jax.named_scope`` — ``zo.sample``
+(z and its dense scatter), ``zo.perturb`` (the perturbed parameters),
+``zo.forward`` (the two loss evaluations) and ``zo.update`` (g and the
+delta update) — so each compiled instruction's ``op_name`` metadata says
+which phase it belongs to.  Scopes change metadata only, never numbers.
 """
 from __future__ import annotations
 
@@ -48,9 +54,15 @@ def _dual_losses(loss_fn, backing, base_flat, z_flat, eps, batch):
 
     z_flat comes pre-masked from ``backing.expand`` (zero off the space
     coordinates), so the kernels run without the mask operand stream."""
-    w_plus, w_minus = zo_dual_perturb_flat(base_flat, z_flat, None, eps)
-    return (loss_fn(backing.unflatten(w_plus), batch),
-            loss_fn(backing.unflatten(w_minus), batch))
+    with jax.named_scope("zo.perturb"):
+        w_plus, w_minus = zo_dual_perturb_flat(base_flat, z_flat, None, eps)
+        p_plus = backing.unflatten(w_plus)
+    with jax.named_scope("zo.forward"):
+        lp = loss_fn(p_plus, batch)
+    with jax.named_scope("zo.perturb"):
+        p_minus = backing.unflatten(w_minus)
+    with jax.named_scope("zo.forward"):
+        return lp, loss_fn(p_minus, batch)
 
 
 def _multi_dir_update(loss_fn, backing, space, base_flat, key, eps: float,
@@ -63,11 +75,13 @@ def _multi_dir_update(loss_fn, backing, space, base_flat, key, eps: float,
     accumulator (not [K, n_pad]) and the loss graph compiles once."""
 
     def one(acc, k):
-        z_flat = backing.expand(space.sample_z(k))
+        with jax.named_scope("zo.sample"):
+            z_flat = backing.expand(space.sample_z(k))
         lp, lm = _dual_losses(loss_fn, backing, base_flat, z_flat, eps,
                               batch)
-        g = _maybe_quantize((lp - lm) / (2.0 * eps), k, quantize)
-        return acc + g * z_flat, g
+        with jax.named_scope("zo.update"):
+            g = _maybe_quantize((lp - lm) / (2.0 * eps), k, quantize)
+            return acc + g * z_flat, g
 
     upd_sum, gs = jax.lax.scan(one, jnp.zeros((backing.n_pad,), jnp.float32),
                                jax.random.split(key, n_dirs))
@@ -84,13 +98,23 @@ def projected_gradient(loss_fn: Callable, params, space, delta, z, eps: float,
     not GSPMD-representable; see core/dispatch.py)."""
     backing = get_backing(space, params)
     if resolve_backend(backend, backing, sharded=sharded) == "ref":
-        lp = loss_fn(space.add(params, delta + eps * z), batch)
-        lm = loss_fn(space.add(params, delta - eps * z), batch)
+        with jax.named_scope("zo.perturb"):
+            p_plus = space.add(params, delta + eps * z)
+        with jax.named_scope("zo.forward"):
+            lp = loss_fn(p_plus, batch)
+        with jax.named_scope("zo.perturb"):
+            p_minus = space.add(params, delta - eps * z)
+        with jax.named_scope("zo.forward"):
+            lm = loss_fn(p_minus, batch)
+        with jax.named_scope("zo.update"):
+            return (lp - lm) / (2.0 * eps)
+    with jax.named_scope("zo.perturb"):
+        base = backing.flatten(params) + backing.expand(delta)
+    with jax.named_scope("zo.sample"):
+        z_flat = backing.expand(z)
+    lp, lm = _dual_losses(loss_fn, backing, base, z_flat, eps, batch)
+    with jax.named_scope("zo.update"):
         return (lp - lm) / (2.0 * eps)
-    base = backing.flatten(params) + backing.expand(delta)
-    lp, lm = _dual_losses(loss_fn, backing, base, backing.expand(z), eps,
-                          batch)
-    return (lp - lm) / (2.0 * eps)
 
 
 def local_step(loss_fn: Callable, params, space, delta, key, eps: float,
@@ -114,38 +138,48 @@ def local_step(loss_fn: Callable, params, space, delta, key, eps: float,
         return _local_step_ref(loss_fn, params, space, delta, key, eps, lr,
                                batch, n_dirs, quantize)
 
-    base = backing.flatten(params) + backing.expand(delta)
+    with jax.named_scope("zo.perturb"):
+        base = backing.flatten(params) + backing.expand(delta)
     if n_dirs == 1:
-        z = space.sample_z(key)
-        lp, lm = _dual_losses(loss_fn, backing, base, backing.expand(z), eps,
-                              batch)
-        g = _maybe_quantize((lp - lm) / (2.0 * eps), key, quantize)
-        return delta - lr * g * z, g
+        with jax.named_scope("zo.sample"):
+            z = space.sample_z(key)
+            z_flat = backing.expand(z)
+        lp, lm = _dual_losses(loss_fn, backing, base, z_flat, eps, batch)
+        with jax.named_scope("zo.update"):
+            g = _maybe_quantize((lp - lm) / (2.0 * eps), key, quantize)
+            return delta - lr * g * z, g
 
     upd, gs = _multi_dir_update(loss_fn, backing, space, base, key, eps,
                                 n_dirs, batch, quantize)
-    return delta - lr * backing.restrict(upd), gs
+    with jax.named_scope("zo.update"):
+        return delta - lr * backing.restrict(upd), gs
 
 
 def _local_step_ref(loss_fn, params, space, delta, key, eps, lr, batch,
                     n_dirs, quantize=None):
     if n_dirs == 1:
-        z = space.sample_z(key)
+        with jax.named_scope("zo.sample"):
+            z = space.sample_z(key)
         g = projected_gradient(loss_fn, params, space, delta, z, eps, batch,
                                backend="ref")
-        g = _maybe_quantize(g, key, quantize)
-        return delta - lr * g * z, g
+        with jax.named_scope("zo.update"):
+            g = _maybe_quantize(g, key, quantize)
+            return delta - lr * g * z, g
 
     def one(k):
-        z = space.sample_z(k)
+        with jax.named_scope("zo.sample"):
+            z = space.sample_z(k)
         g = projected_gradient(loss_fn, params, space, delta, z, eps, batch,
                                backend="ref")
-        g = _maybe_quantize(g, k, quantize)
-        return g * z, g
+        with jax.named_scope("zo.update"):
+            g = _maybe_quantize(g, k, quantize)
+            return g * z, g
 
-    keys = jax.random.split(key, n_dirs)
+    with jax.named_scope("zo.sample"):
+        keys = jax.random.split(key, n_dirs)
     gz, gs = jax.vmap(one)(keys)
-    return delta - lr * gz.mean(0), gs
+    with jax.named_scope("zo.update"):
+        return delta - lr * gz.mean(0), gs
 
 
 def make_local_run(loss_fn: Callable, space, eps: float, lr: float,
@@ -181,27 +215,34 @@ def make_local_run(loss_fn: Callable, space, eps: float, lr: float,
 
             return jax.lax.scan(step, delta0, (keys, batches))
 
-        w_flat = backing.flatten(params)
+        with jax.named_scope("zo.perturb"):
+            w_flat = backing.flatten(params)
         # dense z buffer carried across the scan: the coordinate set is
         # static, so each step refreshes the sparse values in place
         # (scatter_into) instead of re-materializing n_pad zeros
-        z0 = jnp.zeros((backing.n_pad,), jnp.float32)
+        with jax.named_scope("zo.sample"):
+            z0 = jnp.zeros((backing.n_pad,), jnp.float32)
 
         def step(carry, inp):
             delta_dense, z_buf = carry
             key, batch = inp
-            base = w_flat + delta_dense
+            with jax.named_scope("zo.perturb"):
+                base = w_flat + delta_dense
             if n_dirs == 1:
-                z_flat = backing.scatter_into(z_buf, space.sample_z(key))
+                with jax.named_scope("zo.sample"):
+                    z_flat = backing.scatter_into(z_buf, space.sample_z(key))
                 lp, lm = _dual_losses(loss_fn, backing, base, z_flat, eps,
                                       batch)
-                g = _maybe_quantize((lp - lm) / (2.0 * eps), key, quantize)
-                return (zo_fused_update_flat(delta_dense, z_flat, None,
-                                             -lr * g), z_flat), g
+                with jax.named_scope("zo.update"):
+                    g = _maybe_quantize((lp - lm) / (2.0 * eps), key,
+                                        quantize)
+                    return (zo_fused_update_flat(delta_dense, z_flat, None,
+                                                 -lr * g), z_flat), g
             upd, gs = _multi_dir_update(loss_fn, backing, space, base, key,
                                         eps, n_dirs, batch, quantize)
-            return (zo_fused_update_flat(delta_dense, upd, None, -lr),
-                    z_buf), gs
+            with jax.named_scope("zo.update"):
+                return (zo_fused_update_flat(delta_dense, upd, None, -lr),
+                        z_buf), gs
 
         (delta_T, _), gs = jax.lax.scan(step, (backing.expand(delta0), z0),
                                         (keys, batches))

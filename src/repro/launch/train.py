@@ -35,11 +35,11 @@ import dataclasses
 import json
 import os
 import sys
-import time
 
 import jax
 import numpy as np
 
+from repro import obs
 from repro.checkpoint.state import FINAL_NAME, LATEST_NAME
 from repro.configs import get_config
 from repro.configs.base import FLConfig
@@ -170,7 +170,26 @@ def main(argv=None):
                     help="uplink codec for the ZO scalars "
                          "(core/quantize.py exact-replay quantizer)")
     a = ap.parse_args(argv)
+    with obs.span("train.total") as total:
+        server, m = _train(a, ap)
+    print(f"final: acc={m['acc']:.4f} loss={m['loss']:.4f} "
+          f"({total.seconds:.0f}s total)  comm: up={server.comm.up_bytes}B "
+          f"down={server.comm.down_bytes}B")
+    # where the run's time went: span seconds by name, counters, and
+    # compile seconds by program (repro/obs.py)
+    print("obs " + json.dumps(obs.totals(), sort_keys=True))
+    if a.out:
+        os.makedirs(os.path.dirname(a.out) or ".", exist_ok=True)
+        with open(a.out, "w") as f:
+            json.dump({"history": server.history, "final": m,
+                       "args": vars(a)}, f, indent=1)
+        print("wrote", a.out)
+    return server
 
+
+def _train(a, ap):
+    """Build the model, coordinate space, clients and server from the
+    parsed flags and run the rounds; returns (server, final metrics)."""
     cfg = TINY if a.arch == "tiny" else get_config(a.arch)
     if a.method == "lora" and cfg.lora_rank == 0:
         cfg = cfg.replace(lora_rank=4)
@@ -191,9 +210,10 @@ def main(argv=None):
     lm_loss_fn = lambda p, b: model.loss(p, b)
     pre = pretrain_batches(spec, n_batches=8, batch_size=32, seed=a.seed + 3)
 
-    t0 = time.time()
-    space = build_space(a.method, lm_loss_fn, params, pre, a.density, a.seed)
-    print(f"space: n={space.n:,} coords ({time.time() - t0:.1f}s)")
+    with obs.span("train.space") as sp:
+        space = build_space(a.method, lm_loss_fn, params, pre, a.density,
+                            a.seed)
+    print(f"space: n={space.n:,} coords ({sp.seconds:.1f}s)")
 
     train = sample_dataset(spec, 2048, seed=a.seed + 1)
     ev = sample_dataset(spec, 512, seed=a.seed + 2)
@@ -267,17 +287,7 @@ def main(argv=None):
         final = server.save_checkpoint(os.path.join(a.checkpoint_dir,
                                                     FINAL_NAME))
         print("wrote", final)
-    m = server.evaluate(eval_batch)
-    print(f"final: acc={m['acc']:.4f} loss={m['loss']:.4f} "
-          f"({time.time() - t0:.0f}s total)  comm: up={server.comm.up_bytes}B "
-          f"down={server.comm.down_bytes}B")
-    if a.out:
-        os.makedirs(os.path.dirname(a.out) or ".", exist_ok=True)
-        with open(a.out, "w") as f:
-            json.dump({"history": server.history, "final": m,
-                       "args": vars(a)}, f, indent=1)
-        print("wrote", a.out)
-    return server
+    return server, server.evaluate(eval_batch)
 
 
 if __name__ == "__main__":
