@@ -90,6 +90,7 @@ class FlatBacking:
                 self._idx_concrete = concrete
         self._global_index = None
         self._mask = None
+        self._sorted_unique = None
 
     @property
     def global_index(self):
@@ -137,6 +138,21 @@ class FlatBacking:
             self._mask = mask
             return mask
         return jnp.zeros((self.n_pad,), jnp.float32).at[gidx].set(1.0)
+
+    @property
+    def sorted_unique(self) -> bool:
+        """Whether every leaf's indices are strictly increasing (so sorted
+        and unique), checked once on the concrete index leaves.  Traced
+        index trees (dry-run) never qualify.  Only then may a scatter at
+        them declare ``indices_are_sorted``/``unique_indices``: the index
+        contract allows any order, and a false declaration gives wrong
+        numbers."""
+        if self._sorted_unique is None:
+            self._sorted_unique = self.identity or (
+                self._idx_concrete and all(
+                    bool(np.all(np.diff(np.asarray(i)) > 0))
+                    for i in self._idx_leaves))
+        return self._sorted_unique
 
     @property
     def supported(self) -> bool:

@@ -212,10 +212,14 @@ class FederatedZO:
                 self.backend, get_backing(self.space, self.params),
                 sharded=self.plan is not None, dense_carry=n_group)
             self.zo_routes[key] = route
+            # only rule "tp" runs the loop on GSPMD-sharded leaves; under
+            # shard_group each device holds its clients' whole weights
             run = ZO.make_local_run(self.loss_fn, self.space, self.fl.eps,
                                     self.fl.lr,
                                     n_dirs=getattr(self.fl, "n_dirs", 1),
                                     backend=route,
+                                    sharded=(self.plan is not None
+                                             and self.plan.rule == "tp"),
                                     quantize=self.codec.jax_spec())
 
             def group(params, keys, batches):

@@ -61,8 +61,9 @@ class MaskedSpace(_FlatSpace):
     """Sparse coordinate space from per-leaf flat index arrays.
 
     ``idx_tree`` has the same treedef as ``params``; each leaf is an int32
-    array of flat indices into the (raveled) parameter leaf.  Leaves with no
-    selected coordinates hold an empty array.
+    array of distinct flat indices into the (raveled) parameter leaf, in
+    any order (the order fixes which entry of a value vector lands where).
+    Leaves with no selected coordinates hold an empty array.
     """
 
     def __init__(self, idx_tree):

@@ -151,8 +151,10 @@ def test_round_records_fl_spans_in_order_with_comm_bytes(prob):
         assert [s["name"] for s in opened] == ROUND_SPANS[:-1]
     assert obs.totals()["span_attrs"] == {"fl.round": {
         "up_bytes": srv.comm.up_bytes, "down_bytes": srv.comm.down_bytes}}
-    # one group program built, for the first round only
-    assert obs.export()["counters"] == {"fl.programs_built": 1}
+    # one group program built, for the first round only, and traced once
+    # on the ref route's in-place perturb
+    assert obs.export()["counters"] == {"fl.programs_built": 1,
+                                        "zo.perturb_inplace": 1}
     first = [c for c in obs.export()["compiles"]
              if c["program"] == "jit(group)" and c["kind"] == "compile"]
     assert len(first) == 1 and first[0]["span"] == "fl.group"

@@ -13,6 +13,7 @@ this file.
 """
 import functools
 import os
+import re
 
 import numpy as np
 import pytest
@@ -108,6 +109,99 @@ def test_flash_decode_compiles(one_chip):
     lengths = _sds((B,), jnp.int32, one_chip)
     _compile_hlo(functools.partial(ops.flash_decode, interpret=False),
                  q, cache, cache, lengths)
+
+
+def _step_loop_ops(hlo: str):
+    """(opcode, element count) of each instruction in a while loop that
+    runs inside another: the ZO step loop inside the group's map over
+    clients, the forward's layer loops and all they call.  A tuple result
+    counts its first element.  An async copy between memory spaces (a
+    ``copy-start`` whose source or destination is in another space than
+    HBM: the compiler's prefetch of a weight into on-chip memory, or its
+    eviction) is named ``copy-start:memory-space``."""
+    comps, body, entry = {}, None, None
+    for line in hlo.splitlines():
+        head = re.match(r"(ENTRY )?%([\w.\-]+) .*\{$", line)
+        if head:
+            body = comps.setdefault(head.group(2), [])
+            entry = entry or (head.group(2) if head.group(1) else None)
+        elif body is not None and line.startswith("  "):
+            body.append(line)
+    callees = {c: {n for l in ls for n in re.findall(r"%([\w.\-]+)", l)
+                   if n in comps and n != c} for c, ls in comps.items()}
+    loops = {c: {b for l in ls for b in re.findall(r"body=%([\w.\-]+)", l)}
+             for c, ls in comps.items()}
+
+    def reach(c, seen):
+        if c not in seen:
+            seen.add(c)
+            for d in callees[c]:
+                reach(d, seen)
+        return seen
+
+    def bodies_under(c):
+        return {b for d in reach(c, set()) for b in loops[d]}
+
+    inner = set()
+    for b in bodies_under(entry):
+        for nested in bodies_under(b) - {b}:
+            reach(nested, inner)
+    ops = []
+    for c in inner:
+        for l in comps[c]:
+            shape = re.match(r"\s*(?:ROOT )?%\S+ = \(?\w+\[([\d,]*)\]", l)
+            op = re.search(r" ([a-z][\w-]*)\(%", l)
+            if shape and op:
+                name = op.group(1)
+                if name == "copy-start" and "S(" in l[:op.start()]:
+                    name += ":memory-space"
+                ops.append((name, int(np.prod(
+                    [int(d) for d in shape.group(1).split(",") if d]))))
+    return ops
+
+
+def test_zo_group_perturbs_in_place(one_chip):
+    """The client group program the server builds (4 clients x 5 ZO steps
+    on the ``ref`` route, a random mask at density 1e-3) at Qwen2-1.5B's
+    widths, two layers deep (the leaves are stacked, and their minor dims
+    set the layout).  The in-place perturb leaves no whole-leaf copy or
+    relayout of a masked leaf, and no sort of a leaf's indices, inside the
+    step loop."""
+    from repro.core import make_local_run
+    from repro.core.masks import random_mask
+    from repro.data.synthetic import TaskSpec, make_task_fns
+    from repro.models import Model
+
+    K, T, b, S = 4, 5, 16, 256
+    model = Model(CFG.replace(n_layers=2))
+    abstract = model.abstract_params()
+    space = random_mask(abstract, density=1e-3, seed=0, balanced=False)
+    loss, _, _ = make_task_fns(model, TaskSpec(
+        vocab=CFG.vocab, n_classes=4, seq_len=S))
+    run = make_local_run(loss, space, 1e-3, 5e-2, backend="ref")
+
+    def group(params, keys, batches):
+        zeros = jnp.zeros((space.n,), jnp.float32)
+        return jax.lax.map(lambda bt: run(params, keys, bt, zeros), batches)
+
+    params = jax.tree.map(lambda a: _sds(a.shape, a.dtype, one_chip),
+                          abstract)
+    keys = jax.ShapeDtypeStruct((T,), jax.random.key(0).dtype,
+                                sharding=one_chip)
+    batches = {"tokens": _sds((K, T, b, S), jnp.int32, one_chip),
+               "label": _sds((K, T, b), jnp.int32, one_chip)}
+    hlo = jax.jit(group).lower(params, keys, batches).compile().as_text()
+    masked = {int(np.prod(p.shape)) for p, i in zip(
+        jax.tree.leaves(abstract), jax.tree.leaves(space.idx_tree))
+        if i.shape[0]}
+    ops = _step_loop_ops(hlo)
+    assert any(op == "scatter" for op, _ in ops)  # the loop was found
+    whole = [(op, n) for op, n in ops
+             if op in ("copy", "copy-start", "reshape") and n in masked]
+    assert not whole
+    # no sort of a leaf's indices: the one sort is the step's permutation
+    # of delta +- eps z into the scatters' order, over all n coordinates
+    assert [n for op, n in ops if op == "sort"] == [space.n]
 
 
 def test_shapes_are_qwen2_1_5b():
