@@ -1,6 +1,10 @@
-"""qwen3-4b — qk_norm, GQA [hf:Qwen/Qwen3-8B family].
+"""qwen3-4b — qk_norm, GQA [hf:Qwen/Qwen3-4B].
 
-36L d_model=2560 32H (kv=8) d_ff=9728 vocab=151936, head_dim=128.
+As published in Qwen3-4B's ``config.json`` (Qwen3 Technical Report,
+arXiv:2505.09388): 36L d_model=2560 32H (kv=8) head_dim=128 (so the query
+is 4096 wide), d_ff=9728, vocab=151936, rms_norm_eps=1e-6,
+rope_theta=1e6, RMSNorm on q and k per head, no q/k/v bias, and the
+output head tied to the embedding: 4,022,468,096 parameters.
 """
 from repro.configs.base import ModelConfig
 
@@ -16,5 +20,6 @@ CONFIG = ModelConfig(
     vocab=151936,
     rope_theta=1_000_000.0,
     qk_norm=True,
-    source="hf:Qwen/Qwen3-8B",
+    tie_embeddings=True,
+    source="hf:Qwen/Qwen3-4B",
 )

@@ -89,10 +89,16 @@ def _global_topk_indices(score_tree, density: float):
 def sensitivity_mask(loss_fn, params, pretrain_batches, density: float
                      ) -> MaskedSpace:
     """MEERKAT's mask: global top-u by avg squared pre-training gradient.
-    ``mask.scores`` spans the dispatch of the gradient accumulation."""
+    ``mask.scores`` spans the dispatch of the gradient accumulation; the
+    counter ``mask.coords.<leaf>`` adds each leaf's chosen coordinates
+    (``mask.coords.stack.p0.wq``), which sum to the mask's count."""
     with obs.span("mask.scores"):
         scores = sensitivity_scores(loss_fn, params, pretrain_batches)
-    return MaskedSpace(_global_topk_indices(scores, density))
+    idx_tree = _global_topk_indices(scores, density)
+    for path, ix in jax.tree_util.tree_flatten_with_path(idx_tree)[0]:
+        obs.count("mask.coords." + jax.tree_util.keystr(
+            path, simple=True, separator="."), int(ix.shape[0]))
+    return MaskedSpace(idx_tree)
 
 
 def magnitude_mask(params, density: float) -> MaskedSpace:

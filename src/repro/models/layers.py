@@ -103,8 +103,9 @@ def _project_qkv(x, p, cfg):
         k = k + p["bk"].reshape(cfg.n_kv_heads, hd)
         v = v + p["bv"].reshape(cfg.n_kv_heads, hd)
     if cfg.qk_norm:
-        q = rmsnorm(q, p["q_norm"], cfg.norm_eps)
-        k = rmsnorm(k, p["k_norm"], cfg.norm_eps)
+        with jax.named_scope("fwd.qk_norm"):
+            q = rmsnorm(q, p["q_norm"], cfg.norm_eps)
+            k = rmsnorm(k, p["k_norm"], cfg.norm_eps)
     return q, k, v
 
 

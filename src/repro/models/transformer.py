@@ -1,5 +1,12 @@
 """Model application: training forward, prefill, and one-token decode, for
 every architecture family, scanning over stacked layer periods.
+
+The training forward names its parts with ``jax.named_scope`` — ``fwd.attn``
+(the attention mixer; ``fwd.qk_norm`` inside it where the model norms q and
+k, in ``layers._project_qkv``), ``fwd.mlp`` (the dense feed-forward) and
+``fwd.head`` (the final norm and the output head) — so that, nested in the
+ZO loop's ``zo.forward``, each compiled instruction's ``op_name`` says which
+part of the forward it belongs to.  Scopes change metadata only.
 """
 from __future__ import annotations
 
@@ -133,13 +140,16 @@ def _mixer_fwd(x, lp, mixer, cfg, ctx, positions, enc_kv):
     h = L.apply_norm(x, lp["norm"], cfg.norm, cfg.norm_eps)
     if mixer in ("attn", "local_attn"):
         local = mixer == "local_attn"
-        if ctx.attn_q_block and x.shape[1] % ctx.attn_q_block == 0 \
-                and x.shape[1] > ctx.attn_q_block:
-            y = L.self_attention_chunked(h, lp, cfg, positions, local=local,
-                                         q_block=ctx.attn_q_block,
-                                         unroll=ctx.unroll_chunks, ctx=ctx)
-        else:
-            y = L.self_attention(h, lp, cfg, positions, local=local, ctx=ctx)
+        with jax.named_scope("fwd.attn"):
+            if ctx.attn_q_block and x.shape[1] % ctx.attn_q_block == 0 \
+                    and x.shape[1] > ctx.attn_q_block:
+                y = L.self_attention_chunked(
+                    h, lp, cfg, positions, local=local,
+                    q_block=ctx.attn_q_block, unroll=ctx.unroll_chunks,
+                    ctx=ctx)
+            else:
+                y = L.self_attention(h, lp, cfg, positions, local=local,
+                                     ctx=ctx)
     elif mixer == "mamba":
         y = SSM.mamba_forward(h, lp, cfg.ssm, chunk=ctx.mamba_chunk,
                               unroll=ctx.unroll_chunks, mode=ctx.mamba_mode)
@@ -167,7 +177,8 @@ def _ffn_fwd(x, lp, ffn, cfg, ctx, token_valid=None):
         return x, aux
     h = L.apply_norm(x, lp["norm2"], cfg.norm, cfg.norm_eps)
     if ffn == "dense":
-        y = L.mlp(h, lp, cfg)
+        with jax.named_scope("fwd.mlp"):
+            y = L.mlp(h, lp, cfg)
     else:
         y, aux = MOE.moe_ffn(h, lp, cfg.moe, cfg.act, ctx, valid=token_valid)
     if cfg.post_norms and "post_norm2" in lp:
@@ -234,8 +245,9 @@ def forward(params, batch, cfg: ModelConfig, ctx: ShardCtx = DEFAULT_CTX):
                                   cfg, ctx)
         enc_kv = enc_out  # per-layer K/V projected inside apply via lp: see below
     x, aux = _stack_with_cross(x, params["stack"], cfg, ctx, positions, enc_kv)
-    x = L.apply_norm(x, params["final_norm"], cfg.norm, cfg.norm_eps)
-    logits = unembed(x, params, cfg)
+    with jax.named_scope("fwd.head"):
+        x = L.apply_norm(x, params["final_norm"], cfg.norm, cfg.norm_eps)
+        logits = unembed(x, params, cfg)
     if cfg.frontend == "vision_stub":
         logits = logits[:, -batch["tokens"].shape[1]:]
     return logits, aux

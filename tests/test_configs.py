@@ -7,8 +7,10 @@ EXPECTED = {
                        vocab=50304),
     "whisper-small": dict(n_layers=12, d_model=768, n_heads=12, d_ff=3072,
                           vocab=51865),
+    # as published: tied head, the embedding counted once
     "qwen3-4b": dict(n_layers=36, d_model=2560, n_heads=32, n_kv_heads=8,
-                     d_ff=9728, vocab=151936),
+                     d_ff=9728, vocab=151936, tie_embeddings=True,
+                     param_count=4_022_468_096),
     "kimi-k2-1t-a32b": dict(n_layers=61, d_model=7168, n_heads=64,
                             n_kv_heads=8, vocab=163840),
     "phi3.5-moe-42b-a6.6b": dict(n_layers=32, d_model=4096, n_heads=32,
@@ -32,9 +34,11 @@ def test_all_assigned_present():
 
 @pytest.mark.parametrize("name", sorted(EXPECTED))
 def test_exact_config_values(name):
+    from repro.models import param_count
     cfg = get_config(name)
     for k, v in EXPECTED[name].items():
-        assert getattr(cfg, k) == v, (name, k, getattr(cfg, k), v)
+        got = param_count(cfg) if k == "param_count" else getattr(cfg, k)
+        assert got == v, (name, k, got, v)
 
 
 def test_moe_configs():

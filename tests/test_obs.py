@@ -1,7 +1,8 @@
 """The program's spans, counters and compile records (``repro/obs.py``),
 and where the program opens them: the federated round's ``fl.*`` spans,
-mask calibration's ``mask.*`` spans and the ZO loop's ``zo.*`` scopes,
-which change the compiled program's metadata and none of its numbers."""
+mask calibration's ``mask.*`` spans and ``mask.coords.*`` counters, and
+the ZO loop's ``zo.*`` and the forward's ``fwd.*`` scopes, which change
+the compiled program's metadata and none of its numbers."""
 import contextlib
 import re
 
@@ -181,9 +182,53 @@ def test_mask_calibration_spans(prob):
                      "mask.to_device"]
 
 
-def _scopes_in(hlo: str) -> set:
+def test_mask_coords_counters_sum_to_the_mask(prob):
+    """One ``mask.coords.<leaf>`` counter per leaf, each that leaf's
+    chosen coordinates, summing to round(N * density)."""
+    pre = [{"tokens": np.asarray(sample_dataset(SPEC, 4, seed=9)["tokens"])}]
+    model = prob["model"]
+    space = sensitivity_mask(lambda p, b: model.loss(p, b), prob["params"],
+                             pre, 1e-2)
+    coords = {k[len("mask.coords."):]: v
+              for k, v in obs.export()["counters"].items()
+              if k.startswith("mask.coords.")}
+    paths = jax.tree_util.tree_flatten_with_path(space.idx_tree)[0]
+    assert coords == {jax.tree_util.keystr(path, simple=True, separator="."):
+                      int(ix.shape[0]) for path, ix in paths}
+    assert coords["stack.p0.wq"] == len(space.idx_tree["stack"]["p0"]["wq"])
+    n = sum(x.size for x in jax.tree.leaves(prob["params"]))
+    assert sum(coords.values()) == space.n == round(n * 1e-2)
+
+
+def _scopes_in(hlo: str, prefix: str = "zo.") -> set:
     names = re.findall(r'op_name="([^"]*)"', hlo)
-    return {p for n in names for p in n.split("/") if p.startswith("zo.")}
+    return {p for n in names for p in n.split("/") if p.startswith(prefix)}
+
+
+@pytest.mark.parametrize("arch", ["qwen2-1.5b", "qwen3-4b"])
+def test_group_program_carries_forward_scopes(arch):
+    """The forward's ``fwd.*`` scopes reach the group program's
+    ``op_name`` metadata, nested in ``zo.forward``; ``fwd.qk_norm`` only
+    where the model norms q and k."""
+    from repro.configs import get_config
+    from repro.models import Model
+    cfg = get_config(arch).reduced()
+    model = Model(cfg)
+    params = model.init(jax.random.key(0))
+    loss, _, _ = make_task_fns(model, SPEC)
+    space = random_mask(params, density=1e-2, seed=0, balanced=False)
+    srv = mk_server(dict(loss=loss, params=params, space=space))
+    srv.run_round()
+    hlo = srv.group_hlo_text(2, 2)
+    want = {"fwd.attn", "fwd.mlp", "fwd.head"}
+    assert _scopes_in(hlo, "fwd.") == (want | {"fwd.qk_norm"}
+                                       if cfg.qk_norm else want)
+    # (an op that a custom-JVP function such as the softmax lowers keeps
+    # only the scopes opened inside its enclosing layer loop)
+    nested = {p for n in re.findall(r'op_name="([^"]*)"', hlo)
+              if "zo.forward" in n.split("/")
+              for p in n.split("/") if p.startswith("fwd.")}
+    assert nested == _scopes_in(hlo, "fwd.")
 
 
 @pytest.mark.parametrize("backend", ["ref", "pallas"])
@@ -201,9 +246,15 @@ def test_group_hlo_text_carries_every_zo_scope(prob, backend):
 
 def _strip_metadata(hlo: str) -> str:
     """The computations, without the source tables before them and
-    without each instruction's metadata."""
+    without each instruction's metadata, each name replaced by the order
+    of its first appearance (a scope may change the numbers XLA gives
+    the names, ``%broadcast_in_dim.895`` for ``.891``, and nothing else)."""
     body = hlo[re.search(r"\n(%|ENTRY)", hlo).start():]
-    return re.sub(r", metadata=\{[^}]*\}", "", body)
+    body = re.sub(r", metadata=\{[^}]*\}", "", body)
+    names = {}
+    return re.sub(r"%[\w.\-]+",
+                  lambda m: names.setdefault(m.group(0), f"%{len(names)}"),
+                  body)
 
 
 @pytest.mark.parametrize("backend", ["ref", "pallas"])

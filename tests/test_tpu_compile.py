@@ -160,9 +160,9 @@ def _step_loop_ops(hlo: str):
     return ops
 
 
-def test_zo_group_perturbs_in_place(one_chip):
+def _check_group_in_place(one_chip, cfg):
     """The client group program the server builds (4 clients x 5 ZO steps
-    on the ``ref`` route, a random mask at density 1e-3) at Qwen2-1.5B's
+    on the ``ref`` route, a random mask at density 1e-3) at ``cfg``'s
     widths, two layers deep (the leaves are stacked, and their minor dims
     set the layout).  The in-place perturb leaves no whole-leaf copy or
     relayout of a masked leaf, and no sort of a leaf's indices, inside the
@@ -173,11 +173,11 @@ def test_zo_group_perturbs_in_place(one_chip):
     from repro.models import Model
 
     K, T, b, S = 4, 5, 16, 256
-    model = Model(CFG.replace(n_layers=2))
+    model = Model(cfg.replace(n_layers=2))
     abstract = model.abstract_params()
     space = random_mask(abstract, density=1e-3, seed=0, balanced=False)
     loss, _, _ = make_task_fns(model, TaskSpec(
-        vocab=CFG.vocab, n_classes=4, seq_len=S))
+        vocab=cfg.vocab, n_classes=4, seq_len=S))
     run = make_local_run(loss, space, 1e-3, 5e-2, backend="ref")
 
     def group(params, keys, batches):
@@ -202,6 +202,18 @@ def test_zo_group_perturbs_in_place(one_chip):
     # no sort of a leaf's indices: the one sort is the step's permutation
     # of delta +- eps z into the scatters' order, over all n coordinates
     assert [n for op, n in ops if op == "sort"] == [space.n]
+
+
+def test_zo_group_perturbs_in_place(one_chip):
+    """Qwen2-1.5B: 12 query heads over 2 KV heads, q/k/v biases."""
+    _check_group_in_place(one_chip, CFG)
+
+
+def test_zo_group_perturbs_in_place_qwen3(one_chip):
+    """Qwen3-4B: a query wider than d_model ([L, 2560, 4096]), and the
+    [L, 128] q_norm/k_norm leaves, which no (8, 128) tile covers, carried
+    untiled."""
+    _check_group_in_place(one_chip, get_config("qwen3-4b"))
 
 
 def test_shapes_are_qwen2_1_5b():
