@@ -38,7 +38,9 @@ import numpy as np
 from repro import obs
 from repro.core.dispatch import get_backing, resolve_backend
 from repro.core.spaces import MaskedSpace
-from repro.kernels.ops import zo_dual_perturb_flat, zo_fused_update_flat
+from repro.kernels import block_place
+from repro.kernels.ops import (_default_interpret, zo_dual_perturb_flat,
+                               zo_fused_update_flat)
 
 
 def _maybe_quantize(g, key, quantize):
@@ -253,7 +255,18 @@ def _run_ref_inplace(loss_fn, params, space, backing, keys, batches, delta0,
     values and changes no bits, and ``delta`` stays in the space's own
     order.  GSPMD-sharded leaves (``sharded``), whose 1-D view is not
     representable, are scattered at N-D indices in their own shape, as
-    ``MaskedSpace.add`` does, and stay sharded."""
+    ``MaskedSpace.add`` does, and stay sharded.
+
+    A tiled leaf that holds many coordinates is placed block by block
+    instead (``kernels/block_place.py``): a Pallas kernel rewrites, in
+    place, only the ``[bm, 128]`` row blocks of the tile view that hold
+    coordinates, and writes the bits the scatter would.  A leaf takes it
+    where the program is compiled for the TPU and the kernel's cost model,
+    from tables built once here from the concrete positions, beats a
+    point scatter's (``block_place.wins``); every other leaf, and every
+    run off the TPU, keeps the point scatter.  The counters
+    ``zo.perturb.block_coords`` and ``zo.perturb.point_coords`` record the
+    split each time the loop is traced."""
     obs.count("zo.perturb_inplace")
     p_leaves, treedef = jax.tree_util.tree_flatten(params)
     i_leaves = jax.tree_util.tree_leaves(space.idx_tree)
@@ -265,7 +278,7 @@ def _run_ref_inplace(loss_fn, params, space, backing, keys, batches, delta0,
              if not sharded and exact and _tileable(p_leaves[k].shape)}
     # where each live leaf's coordinates lie in its working form, and the
     # order of the space's [n] vectors in which the scatters take them
-    ix, order = {}, []
+    ix, order, blocks = {}, [], {}
     for k in live:
         off = int(space.offsets[k])
         if k in tiled:
@@ -273,12 +286,19 @@ def _run_ref_inplace(loss_fn, params, space, backing, keys, batches, delta0,
             o = np.argsort(pos, kind="stable")
             ix[k] = jnp.asarray(pos[o], jnp.int32)
             order.append(off + o)
+            if not _default_interpret():
+                plan = block_place.plan(pos[o], p_leaves[k].size // _TILE_C)
+                if block_place.wins(plan, p_leaves[k].dtype):
+                    blocks[k] = plan
         else:
             ix[k] = jnp.unravel_index(i_leaves[k], p_leaves[k].shape)
             order.append(off + np.arange(space.sizes[k]))
     # each coordinate's place in that order, the key that sorts into it
     rank = (jnp.asarray(np.argsort(np.concatenate(order)), jnp.int32)
             if tiled else None)
+    n_block = sum(p.n for p in blocks.values())
+    obs.count("zo.perturb.block_coords", n_block)
+    obs.count("zo.perturb.point_coords", space.n - n_block)
 
     def flat(k, w):
         return w.reshape(-1) if k in tiled else w
@@ -292,9 +312,12 @@ def _run_ref_inplace(loss_fn, params, space, backing, keys, batches, delta0,
         segs = space._segments(vec)
         out = []
         for k, w, b in zip(live, work, w0):
-            out.append(flat(k, w).at[ix[k]].set(
-                b + segs[k].astype(w.dtype), mode="drop", **flags
-            ).reshape(w.shape))
+            v = b + segs[k].astype(w.dtype)
+            if k in blocks:
+                new = block_place.place(w.reshape(-1, _TILE_C), v, blocks[k])
+            else:
+                new = flat(k, w).at[ix[k]].set(v, mode="drop", **flags)
+            out.append(new.reshape(w.shape))
         return tuple(out)
 
     def tree(work):

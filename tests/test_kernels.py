@@ -159,3 +159,35 @@ def test_mamba_kernel_mode_matches_scan_mode():
     np.testing.assert_allclose(np.asarray(y_scan, np.float32),
                                np.asarray(y_kern, np.float32),
                                atol=5e-3, rtol=5e-3)
+
+
+def _bits(x):
+    return np.asarray(jax.lax.bitcast_convert_type(
+        x, jnp.uint16 if x.dtype == jnp.bfloat16 else jnp.uint32))
+
+
+@pytest.mark.parametrize("layout", ["spread", "one_block", "skewed"])
+@pytest.mark.parametrize("dtype", DTYPES)
+def test_block_place_matches_scatter_bitwise(dtype, layout):
+    """The block kernel writes the bits a point scatter writes, -0, NaN and
+    infinities included, and leaves every other value as it was."""
+    from repro.kernels import block_place
+    rows = 4 * block_place.BLOCK_ROWS
+    rng = np.random.default_rng(len(layout))
+    block = block_place.BLOCK_ROWS * block_place.LANE
+    if layout == "spread":
+        pos = rng.choice(rows * 128, 900, replace=False)
+    elif layout == "one_block":
+        pos = 2 * block + rng.choice(block, 40, replace=False)
+    else:
+        pos = np.concatenate([rng.choice(block, 1300, replace=False),
+                              3 * block + rng.choice(block, 7, replace=False)])
+    pos = np.sort(pos)
+    p = block_place.plan(pos, rows)
+    w = jax.random.normal(jax.random.key(1), (rows, 128)).astype(dtype)
+    vals = jax.random.normal(jax.random.key(2), (len(pos),)).astype(dtype)
+    vals = vals.at[:4].set(jnp.asarray([-0.0, jnp.nan, jnp.inf, -jnp.inf],
+                                       dtype))
+    want = w.reshape(-1).at[jnp.asarray(pos)].set(vals).reshape(rows, 128)
+    got = jax.jit(lambda w, v: block_place.place(w, v, p))(w, vals)
+    np.testing.assert_array_equal(_bits(got), _bits(want))

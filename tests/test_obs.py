@@ -153,13 +153,32 @@ def test_round_records_fl_spans_in_order_with_comm_bytes(prob):
     assert obs.totals()["span_attrs"] == {"fl.round": {
         "up_bytes": srv.comm.up_bytes, "down_bytes": srv.comm.down_bytes}}
     # one group program built, for the first round only, and traced once
-    # on the ref route's in-place perturb
-    assert obs.export()["counters"] == {"fl.programs_built": 1,
-                                        "zo.perturb_inplace": 1}
+    # on the ref route's in-place perturb, which off the TPU leaves every
+    # coordinate to point scatters
+    assert obs.export()["counters"] == {
+        "fl.programs_built": 1, "zo.perturb_inplace": 1,
+        "zo.perturb.block_coords": 0,
+        "zo.perturb.point_coords": prob["space"].n}
     first = [c for c in obs.export()["compiles"]
              if c["program"] == "jit(group)" and c["kind"] == "compile"]
     assert len(first) == 1 and first[0]["span"] == "fl.group"
     assert first[0]["round"] == 0
+
+
+@pytest.mark.parametrize("backend", ["ref", "pallas"])
+def test_perturb_coords_counters_on_cpu(prob, backend):
+    """Off the TPU the in-place perturb places no coordinate with the block
+    kernel: ``zo.perturb.block_coords`` is 0 and ``point_coords`` the whole
+    space, once per group program traced; the flat route counts neither."""
+    srv = mk_server(prob, backend=backend)
+    srv.run_round()
+    srv.run_round()
+    c = obs.totals()["counters"]
+    if backend == "pallas":
+        assert not any(k.startswith("zo.perturb") for k in c)
+        return
+    assert (c["zo.perturb.block_coords"], c["zo.perturb.point_coords"]) == (
+        0, prob["space"].n)
 
 
 def test_vp_calibration_span(prob):

@@ -118,7 +118,8 @@ def _step_loop_ops(hlo: str):
     counts its first element.  An async copy between memory spaces (a
     ``copy-start`` whose source or destination is in another space than
     HBM: the compiler's prefetch of a weight into on-chip memory, or its
-    eviction) is named ``copy-start:memory-space``."""
+    eviction) is named ``copy-start:memory-space``, and a Pallas kernel's
+    custom call ``tpu_custom_call``."""
     comps, body, entry = {}, None, None
     for line in hlo.splitlines():
         head = re.match(r"(ENTRY )?%([\w.\-]+) .*\{$", line)
@@ -155,27 +156,52 @@ def _step_loop_ops(hlo: str):
                 name = op.group(1)
                 if name == "copy-start" and "S(" in l[:op.start()]:
                     name += ":memory-space"
+                if 'custom_call_target="tpu_custom_call"' in l:
+                    name = "tpu_custom_call"
                 ops.append((name, int(np.prod(
                     [int(d) for d in shape.group(1).split(",") if d]))))
     return ops
 
 
-def _check_group_in_place(one_chip, cfg):
+def _hot_mask(abstract, hot: float, rest: float):
+    """A mask as calibration chooses one: the attention output ``wo`` at
+    density ``hot``, every other leaf at ``rest``."""
+    from repro.core import MaskedSpace
+    rng = np.random.default_rng(0)
+    flat, treedef = jax.tree_util.tree_flatten_with_path(abstract)
+    idx = []
+    for path, leaf in flat:
+        d = hot if jax.tree_util.keystr(path).endswith("['wo']") else rest
+        size = int(np.prod(leaf.shape))
+        idx.append(jnp.asarray(np.sort(rng.choice(
+            size, max(1, round(size * d)), replace=False)), jnp.int32))
+    return MaskedSpace(jax.tree_util.tree_unflatten(treedef, idx))
+
+
+def _check_group_in_place(one_chip, cfg, hot=None):
     """The client group program the server builds (4 clients x 5 ZO steps
-    on the ``ref`` route, a random mask at density 1e-3) at ``cfg``'s
-    widths, two layers deep (the leaves are stacked, and their minor dims
-    set the layout).  The in-place perturb leaves no whole-leaf copy or
-    relayout of a masked leaf, and no sort of a leaf's indices, inside the
-    step loop."""
+    on the ``ref`` route, a random mask at density 1e-3, or ``hot``'s
+    (density of ``wo``, of the rest)) at ``cfg``'s widths, two layers deep
+    (the leaves are stacked, and their minor dims set the layout).  The
+    in-place perturb leaves no whole-leaf copy or relayout of a masked
+    leaf, and no sort of a leaf's indices, inside the step loop.  Each leaf
+    the block kernel places (``kernels/block_place.py``, as the rule in
+    ``_run_ref_inplace`` routes under a TPU compile) is written by two
+    Pallas custom calls a step and by no scatter; the counters split the
+    coordinates as the rule does."""
+    from repro import obs
     from repro.core import make_local_run
     from repro.core.masks import random_mask
+    from repro.core.zo import _tile_positions, _tileable
     from repro.data.synthetic import TaskSpec, make_task_fns
+    from repro.kernels import block_place
     from repro.models import Model
 
     K, T, b, S = 4, 5, 16, 256
     model = Model(cfg.replace(n_layers=2))
     abstract = model.abstract_params()
-    space = random_mask(abstract, density=1e-3, seed=0, balanced=False)
+    space = (random_mask(abstract, density=1e-3, seed=0, balanced=False)
+             if hot is None else _hot_mask(abstract, *hot))
     loss, _, _ = make_task_fns(model, TaskSpec(
         vocab=cfg.vocab, n_classes=4, seq_len=S))
     run = make_local_run(loss, space, 1e-3, 5e-2, backend="ref")
@@ -190,18 +216,38 @@ def _check_group_in_place(one_chip, cfg):
                                 sharding=one_chip)
     batches = {"tokens": _sds((K, T, b, S), jnp.int32, one_chip),
                "label": _sds((K, T, b), jnp.int32, one_chip)}
+    names = ("zo.perturb_inplace", "zo.perturb.block_coords",
+             "zo.perturb.point_coords")
+    before = [obs.totals()["counters"].get(k, 0) for k in names]
     hlo = jax.jit(group).lower(params, keys, batches).compile().as_text()
-    masked = {int(np.prod(p.shape)) for p, i in zip(
+    live = [(p, np.asarray(i)) for p, i in zip(
         jax.tree.leaves(abstract), jax.tree.leaves(space.idx_tree))
-        if i.shape[0]}
+        if i.shape[0]]
+    blocks = [(p, i) for p, i in live if _tileable(p.shape) and
+              block_place.wins(block_place.plan(
+                  np.sort(_tile_positions(i, p.shape)), p.size // 128),
+                  p.dtype)]
+    masked = {int(np.prod(p.shape)) for p, _ in live}
     ops = _step_loop_ops(hlo)
-    assert any(op == "scatter" for op, _ in ops)  # the loop was found
+    # the loop was found
+    assert any(op in ("scatter", "tpu_custom_call") for op, _ in ops)
     whole = [(op, n) for op, n in ops
              if op in ("copy", "copy-start", "reshape") and n in masked]
     assert not whole
     # no sort of a leaf's indices: the one sort is the step's permutation
     # of delta +- eps z into the scatters' order, over all n coordinates
     assert [n for op, n in ops if op == "sort"] == [space.n]
+    for size in {p.size for p, _ in blocks}:
+        writes = [op for op, n in ops if n == size]
+        n_blocks = sum(p.size == size for p, _ in blocks)
+        n_points = sum(p.size == size for p, _ in live) - n_blocks
+        assert writes.count("scatter") == 2 * n_points
+        assert writes.count("tpu_custom_call") == 2 * n_blocks
+    n_block = sum(len(i) for _, i in blocks)
+    after = [obs.totals()["counters"].get(k, 0) for k in names]
+    assert np.subtract(after, before).tolist() == [
+        1, n_block, space.n - n_block]
+    return n_block / space.n
 
 
 def test_zo_group_perturbs_in_place(one_chip):
@@ -214,6 +260,40 @@ def test_zo_group_perturbs_in_place_qwen3(one_chip):
     [L, 128] q_norm/k_norm leaves, which no (8, 128) tile covers, carried
     untiled."""
     _check_group_in_place(one_chip, get_config("qwen3-4b"))
+
+
+@pytest.mark.parametrize("arch,hot,rest,share", [
+    ("qwen2-1.5b", 0.0211, 1.02e-4, 0.7), ("qwen3-4b", 0.0114, 1.56e-4, 0.6)])
+def test_zo_group_places_hot_leaf_by_blocks(one_chip, monkeypatch, arch, hot,
+                                            rest, share):
+    """Compiled for the TPU (the platform test steered here, where the
+    compiler runs without a chip), the group program places ``wo``, which
+    holds most coordinates as in the cells' masks (PERF.md section 5), with
+    the block kernel inside the step loop; the densities are the cells'
+    (``wo``'s share of the mask is smaller at two layers)."""
+    from repro.core import zo
+    from repro.kernels import block_place
+    monkeypatch.setattr(zo, "_default_interpret", lambda: False)
+    monkeypatch.setattr(block_place, "_default_interpret", lambda: False)
+    assert _check_group_in_place(one_chip, get_config(arch),
+                                 (hot, rest)) > share
+
+
+def test_block_place_compiles(one_chip):
+    """The kernel alone on Qwen2-1.5B's attention-output leaf at its 28
+    layers, with the coordinates its calibrated mask holds there."""
+    from repro.kernels import block_place
+    rows = CFG.n_layers * CFG.d_model * CFG.d_model // 128
+    n = 1_393_103
+    pos = np.sort(np.random.default_rng(0).choice(rows * 128, n,
+                                                  replace=False))
+    p = block_place.plan(pos, rows)
+    assert block_place.wins(p, jnp.bfloat16)
+    hlo = _compile_hlo(lambda w, v: block_place.place(w, v, p,
+                                                      interpret=False),
+                       _sds((rows, 128), jnp.bfloat16, one_chip),
+                       _sds((n,), jnp.bfloat16, one_chip))
+    assert hlo.count("tpu_custom_call") == 1
 
 
 def test_shapes_are_qwen2_1_5b():
