@@ -51,7 +51,7 @@ def run(quick: bool = True, seed: int = 0, d: int = 32, K: int = 8,
     rows = []
     for T in Ts:
         rounds = total_steps // T
-        step = jax.jit(make_fl_round_step(loss, space, eps=1e-4, lr=lr, T=T))
+        step = jax.jit(make_fl_round_step(loss, space, eps=1e-4, lr=lr))
         p = params
         tail = []
         for r in range(rounds):

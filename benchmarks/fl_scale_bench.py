@@ -36,9 +36,6 @@ match the measured rows at smaller K).  Fleet gates:
 * ``round_time_sublinear_in_K``       — wall-clock per round grows
   sublinearly in K at fixed cohort size.
 
-``zo_backend="ref"`` everywhere so mesh shapes compare the same per-step
-route (the fused-vs-ref axis is BENCH_zo_step's job).
-
 A CPU harness: each worker runs with ``JAX_PLATFORMS=cpu`` unless the
 caller set it, so its forced host meshes never wait on an accelerator.
 Its timings are XLA:CPU timings, not device numbers.
@@ -124,7 +121,7 @@ def worker(a) -> dict:
     plan = (None if a.mesh == "none"
             else make_fl_plan(spec=a.mesh, rule=a.rule))
     fl = FLConfig(n_clients=a.clients, local_steps=a.T, lr=5e-2, eps=1e-3,
-                  seed=0, zo_backend="ref", batch_size=a.batch,
+                  seed=0, batch_size=a.batch,
                   quantize=a.quantize)
     sampler = (ClientSampler(range(a.clients), m=m, seed=0)
                if fleet and not a.compile_only else None)
@@ -162,8 +159,7 @@ def worker(a) -> dict:
             twin = FederatedZO(
                 loss, params, space,
                 FLConfig(n_clients=a.clients, local_steps=a.T, lr=5e-2,
-                         eps=1e-3, seed=0, zo_backend="ref",
-                         batch_size=a.batch),
+                         eps=1e-3, seed=0, batch_size=a.batch),
                 clients, plan=plan,
                 sampler=(ClientSampler(range(a.clients), m=m, seed=0)
                          if fleet else None))
@@ -418,7 +414,7 @@ def main():
                 if r.get("cohort", 0) == 0]  # refresh the fleet rows
         rows = keep + rows
     result = {"bench": "fl_scale", "rule": a.rule, "T": a.T,
-              "zo_backend": "ref", "fleet_cohort": FLEET_COHORT,
+              "fleet_cohort": FLEET_COHORT,
               "rows": rows, "gates": gates(rows)}
     with open(path, "w") as f:
         json.dump(result, f, indent=1)

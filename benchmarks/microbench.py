@@ -1,7 +1,6 @@
-"""Kernel microbench: interpret-mode correctness + wall timings for every
-Pallas kernel over a shape sweep, against the ref.py jnp oracles — plus the
-end-to-end ZO *step* benchmark (naive pytree route vs fused flat kernel
-route through the dispatch layer), written to runs/bench/BENCH_zo_step.json.
+"""Kernel microbench: interpret-mode correctness + wall timings for the
+GradIP reduction and flash-decode Pallas kernels over a shape sweep,
+against the ref.py jnp oracles.
 
 Timings on CPU interpret mode are NOT TPU performance — they validate the
 kernel bodies; the roofline analysis (launch/roofline.py) covers perf.
@@ -13,12 +12,10 @@ import time
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from benchmarks import common as C
 from repro.kernels import ref
-from repro.kernels.ops import (flash_decode, gradip_flat, zo_dual_perturb_flat,
-                               zo_fused_update_flat)
+from repro.kernels.ops import flash_decode, gradip_flat
 
 
 def _t(fn, *args, reps=3):
@@ -27,236 +24,6 @@ def _t(fn, *args, reps=3):
     for _ in range(reps):
         jax.block_until_ready(fn(*args))
     return (time.time() - t0) / reps
-
-
-def _t_min_group(fns: dict, *args, reps=3) -> dict:
-    """Best-of-reps wall time per function, measured *interleaved* so CPU
-    frequency/cache/contention drift hits every candidate equally — the
-    robust protocol for comparative ms-scale timings on a shared machine.
-    Returns {name: seconds}."""
-    for fn in fns.values():
-        fn(*args)  # compile
-    best = {name: float("inf") for name in fns}
-    for _ in range(reps):
-        for name, fn in fns.items():
-            t0 = time.time()
-            jax.block_until_ready(fn(*args))
-            best[name] = min(best[name], time.time() - t0)
-    return best
-
-
-# ------------------------------------------------- end-to-end ZO step -------
-
-def _step_problem(which: str, seed: int):
-    """A real (model, per-example loss, masked space, batch) at one of the
-    DESIGN.md §7 scale-substituted shapes: the tiny CPU config, or the
-    qwen3-4b architecture via its reduced() variant."""
-    from repro.configs import get_config
-    from repro.configs.tiny import TINY
-    from repro.core import random_mask
-    from repro.data.synthetic import TaskSpec, make_task_fns, sample_dataset
-    from repro.models import Model
-
-    cfg = TINY if which == "tiny" else get_config("qwen3-4b").reduced()
-    spec = TaskSpec(vocab=min(cfg.vocab, 512))
-    model = Model(cfg)
-    params = model.init(jax.random.key(seed))
-    _, per_example, _ = make_task_fns(model, spec)
-    space = random_mask(params, density=1e-2, seed=seed, balanced=False)
-    data = sample_dataset(spec, 32, seed=seed + 1)
-    batch = {k: jnp.asarray(v) for k, v in data.items()}
-    n_params = sum(int(np.prod(l.shape)) for l in jax.tree.leaves(params))
-    return params, per_example, space, batch, n_params
-
-
-def _phase_bench(space, params, reps: int) -> dict:
-    """Isolated perturb+update phase (no model forward), three routes over
-    the same step semantics (see DESIGN.md §6, BENCH_zo_step):
-
-    * fused    — zo_dual_perturb_flat + zo_fused_update_flat (7 HBM passes)
-    * unfused  — the same flat math as separate jnp ops (13 passes); the
-      hardware-transferable fusion comparison: fewer passes wins on any
-      backend, CPU interpret included
-    * scatter  — the pytree ``space.add`` chain.  On CPU its random-access
-      sparse scatters are cheap, so it wins here; on TPU arbitrary-index
-      scatter serializes (and erases GSPMD shardings — DESIGN.md §perf),
-      which is what motivates the flat route
-    """
-    from repro.core import get_backing
-
-    backing = get_backing(space, params)
-    eps, lr, g = 1e-3, 1e-2, 0.5
-
-    @jax.jit
-    def fused(params, key):
-        w = backing.flatten(params)
-        z = backing.expand(space.sample_z(key))
-        wp, wm = zo_dual_perturb_flat(w, z, None, eps)
-        return wp, wm, zo_fused_update_flat(w, z, None, -lr * g)
-
-    @jax.jit
-    def unfused(params, key):
-        w = backing.flatten(params)
-        z = backing.expand(space.sample_z(key))
-        m = jnp.asarray(backing.mask)
-        pert = (eps * z * m).astype(w.dtype)
-        return w + pert, w - pert, w + (-lr * g * z * m).astype(w.dtype)
-
-    @jax.jit
-    def scatter(params, key):
-        z = space.sample_z(key)
-        wp = space.add(params, eps * z)
-        wm = space.add(wp, -2.0 * eps * z)
-        return wp, wm, space.add(wm, (eps - lr * g) * z)
-
-    key = jax.random.key(0)
-    f, u = fused(params, key), unfused(params, key)
-    err = max(float(jnp.max(jnp.abs(a - b))) for a, b in zip(f, u))
-    reps = max(8 * reps, 40)  # phase calls are ms-scale; de-noise hard
-    ts = _t_min_group(dict(fused=fused, unfused=unfused, scatter=scatter),
-                      params, key, reps=reps)
-    return dict(
-        fused_ms=round(ts["fused"] * 1e3, 3),
-        unfused_ms=round(ts["unfused"] * 1e3, 3),
-        scatter_ms=round(ts["scatter"] * 1e3, 3),
-        max_err=err, parity_ok=err < 1e-5)
-
-
-def run_zo_step(quick: bool = True, seed: int = 0) -> dict:
-    """End-to-end ZO train-step benchmark, naive vs fused, per DESIGN.md §6.
-
-    Per arch (tiny and the scale-substituted qwen3_4b-reduced, §7):
-
-    * ``step``  — the T=1 high-frequency MEERKAT step (Alg. 3, the
-      production hot path) measured inside the jitted ``n_steps``-scan of
-      ``fl_step.make_fl_train_loop`` (the compiled training burst) on
-      backend="ref" (naive pytree route) vs backend="pallas" (fused flat
-      route), with output parity over the whole burst.  The scan is the
-      realistic hot loop — and on the fused route it hoists the per-step
-      ``backing.flatten(params)`` / tile re-padding round-trip out of the
-      step (once per burst), which repeated single-step calls paid per
-      step and which inverted the fused-vs-naive comparison on qwen3_4b
-      (ISSUE 4 satellite).
-    * ``phase`` — the perturb/update phase alone (see ``_phase_bench``):
-      ``fused_ge_naive`` asserts the fused kernels beat the *unfused flat
-      chain* they replace, the comparison that transfers across backends.
-      End-to-end CPU numbers also include interpret-mode overhead and a
-      scatter route whose CPU/TPU cost relation is inverted, so they are
-      reported but not gated on this container (see the module docstring).
-    """
-    from repro.core.fl_step import make_fl_train_loop
-
-    reps = 5 if quick else 20
-    e2e_reps = max(6 * reps, 30)  # loop timings gate the bench; de-noise
-    n_steps = 8
-    rows = []
-    for which in ("tiny", "qwen3_4b"):
-        params, per_example, space, batch, n_params = _step_problem(which,
-                                                                    seed)
-        # burst batches: the same batch at every scanned step (bench-only;
-        # data content does not affect route cost)
-        batches = jax.tree.map(
-            lambda x: jnp.broadcast_to(x[None], (n_steps,) + x.shape), batch)
-        args = (params, jax.random.key(seed + 2), batches)
-
-        def build(be):
-            return jax.jit(make_fl_train_loop(
-                per_example, space, eps=1e-3, lr=1e-2, n_clients=4,
-                n_steps=n_steps, backend=be))
-
-        parity_loops = {"naive": build("ref"), "fused": build("pallas")}
-        outs = {be: fn(*args) for be, fn in
-                zip(("ref", "pallas"), parity_loops.values())}
-        g_err = float(jnp.max(jnp.abs(outs["ref"][1] - outs["pallas"][1])))
-        w_err = max(float(jnp.max(jnp.abs(a - b))) for a, b in
-                    zip(jax.tree.leaves(outs["ref"][0]),
-                        jax.tree.leaves(outs["pallas"][0])))
-        # best-of over FRESH jit instances per route, interleaved reps
-        # within each: on this container an individual executable's buffer
-        # placement can land pathologically (a stable ~2x penalty for that
-        # instance), so a single-instance comparison measures allocator
-        # luck, not the route.  Per-route minimum across instances recovers
-        # each route's healthy cost.
-        n_inst = 3
-        naive_ts, fused_ts = [], []
-        for i in range(n_inst):
-            # the parity pair doubles as timing instance 0 (it is a fresh
-            # jit instance of each route; re-building it would only pay
-            # two more burst compiles)
-            loops = parity_loops if i == 0 else {"naive": build("ref"),
-                                                 "fused": build("pallas")}
-            ts = _t_min_group(loops, *args,
-                              reps=max(2, e2e_reps // n_inst))
-            naive_ts.append(ts["naive"])
-            fused_ts.append(ts["fused"])
-        speedup = min(naive_ts) / min(fused_ts)
-        naive_ms = min(naive_ts) * 1e3 / n_steps
-        fused_ms = min(fused_ts) * 1e3 / n_steps
-        phase = _phase_bench(space, params, reps)
-        rows.append(dict(
-            arch=which, n_params=n_params, n_coords=space.n,
-            step_naive_ms=round(naive_ms, 3),
-            step_fused_ms=round(fused_ms, 3),
-            step_naive_per_s=round(1e3 / naive_ms, 2),
-            step_fused_per_s=round(1e3 / fused_ms, 2),
-            step_speedup=round(speedup, 3),
-            phase=phase,
-            phase_speedup=round(phase["unfused_ms"] / phase["fused_ms"], 3),
-            g_max_err=g_err, w_max_err=w_err,
-            parity_ok=g_err < 5e-2 and w_err < 1e-3 and phase["parity_ok"]))
-        r = rows[-1]
-        print(f"  zo_step {which:10s} n={n_params:>9d} coords={space.n:>7d} "
-              f"e2e x{r['step_speedup']:.2f} "
-              f"phase fused={phase['fused_ms']:.1f}ms "
-              f"unfused={phase['unfused_ms']:.1f}ms "
-              f"scatter={phase['scatter_ms']:.1f}ms "
-              f"x{r['phase_speedup']:.2f} "
-              f"{'ok' if r['parity_ok'] else 'FAIL'}")
-    # gate on rows whose phase does measurable work: below ~2 ms the
-    # 7-vs-13-pass difference is microseconds — under the wall-clock timer's
-    # resolution on CPU — so sub-ms rows are reported but not gated (and if
-    # every row is sub-resolution the criterion is vacuously met rather
-    # than decided by noise)
-    gated = [r for r in rows if r["phase"]["fused_ms"] >= 2.0]
-    # strict: fused kernels literally >= the unfused chain on this run.
-    # within_noise (>= 0.85 off-TPU): XLA auto-fuses the unfused jnp chain
-    # on CPU, so the two routes stream comparable bytes and wall-clock
-    # ratios swing ~10-15% on a shared box; the kernels' structural win
-    # (single-read dual output, no mask stream, no scatter) is a TPU
-    # property — see DESIGN.md \u00a76/\u00a7perf.  Both are null when no
-    # row has >= 2 ms of phase work to measure (never decided by noise).
-    floor = 0.85 if jax.default_backend() != "tpu" else 1.0
-    strict = (all(r["phase_speedup"] >= 1.0 for r in gated)
-              if gated else None)
-    within = (all(r["phase_speedup"] >= floor for r in gated)
-              if gated else None)
-    print(f"  zo_step fused_ge_naive={strict} within_noise={within} "
-          f"(gated rows: {[r['arch'] for r in gated]})")
-    return {
-        "table": "zo_step", "rows": rows,
-        "fused_ge_naive": strict,
-        "fused_ge_naive_within_noise": within,
-        "fused_ge_naive_basis":
-            "phase: fused kernels vs the unfused flat chain they replace, "
-            "over rows with >= 2 ms of phase work (null if none qualify). "
-            "fused_ge_naive is the strict >= 1.0 comparison on this run; "
-            "fused_ge_naive_within_noise tolerates 15% CPU timing noise, "
-            "since XLA auto-fuses the unfused chain on CPU and the "
-            "structural fusion win (single-read dual output, no mask "
-            "stream) is realized on TPU. rows[].step_speedup is the "
-            "end-to-end naive-pytree-vs-fused *per-step* comparison "
-            "inside the jitted make_fl_train_loop burst (the "
-            "realistic hot loop, where the fused route builds the "
-            "flat vector once per burst instead of once per step, "
-            "hoisting the per-step flatten that inverted this "
-            "comparison on qwen3_4b, and auto-picks the forward "
-            "strategy by model size: stacked-vmap (w+, w-) forward "
-            "below STACK_FORWARDS_MAX_PARAMS, two sequential "
-            "forwards above): per-route best over fresh jit "
-            "instances x interleaved reps, robust to per-executable "
-            "buffer-placement pathology on shared containers; CPU "
-            "interpret-mode caveats per DESIGN.md \u00a76/\u00a7perf.",
-        "all_ok": all(r["parity_ok"] for r in rows)}
 
 
 def run(quick: bool = True, seed: int = 0) -> dict:
@@ -268,21 +35,6 @@ def run(quick: bool = True, seed: int = 0) -> dict:
         k1, k2, k3, key = jax.random.split(key, 4)
         w = jax.random.normal(k1, (n,), jnp.float32)
         z = jax.random.normal(k2, (n,), jnp.float32)
-        m = (jax.random.uniform(k3, (n,)) < 0.5).astype(jnp.float32)
-        eps = 1e-3
-
-        p, mi = zo_dual_perturb_flat(w, z, m, eps)
-        rp, rm = ref.dual_perturb_ref(w, z, m, eps)
-        err = float(jnp.max(jnp.abs(p - rp)) + jnp.max(jnp.abs(mi - rm)))
-        dt = _t(zo_dual_perturb_flat, w, z, m, eps)
-        rows.append(dict(kernel="zo_dual_perturb", n=n, max_err=err,
-                         ms=dt * 1e3, ok=err < 1e-5))
-
-        u = zo_fused_update_flat(w, z, m, 0.37)
-        err = float(jnp.max(jnp.abs(u - ref.fused_update_ref(w, z, m, 0.37))))
-        dt = _t(zo_fused_update_flat, w, z, m, 0.37)
-        rows.append(dict(kernel="zo_fused_update", n=n, max_err=err,
-                         ms=dt * 1e3, ok=err < 1e-5))
 
         g = gradip_flat(w, z, 1.7)
         rg = ref.gradip_reduce_ref(w, z, 1.7)
@@ -311,10 +63,8 @@ def run(quick: bool = True, seed: int = 0) -> dict:
         print(f"  {r['kernel']:16s} n={r['n']!s:10s} err={r['max_err']:.2e} "
               f"{r['ms']:8.1f}ms {'ok' if r['ok'] else 'FAIL'}")
 
-    step_res = run_zo_step(quick=quick, seed=seed)
-    print("saved:", C.save_result("BENCH_zo_step", step_res))
-    return {"table": "microbench", "rows": rows, "zo_step": step_res,
-            "all_ok": all(r["ok"] for r in rows) and step_res["all_ok"]}
+    return {"table": "microbench", "rows": rows,
+            "all_ok": all(r["ok"] for r in rows)}
 
 
 def main():

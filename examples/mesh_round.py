@@ -60,8 +60,7 @@ K = 4
 def make_server(plan):
     parts = dirichlet_partition(train["label"], K, alpha=0.5, seed=0)
     clients = [Client(k, subset(train, p), 16) for k, p in enumerate(parts)]
-    fl = FLConfig(n_clients=K, local_steps=a.T, lr=5e-2, eps=1e-3,
-                  zo_backend="ref")  # the mesh route's backend — see DESIGN §9
+    fl = FLConfig(n_clients=K, local_steps=a.T, lr=5e-2, eps=1e-3)
     return FederatedZO(loss, params, space, fl, clients, plan=plan)
 
 

@@ -8,8 +8,7 @@ C4-proxy corpus (0.1%-style extreme sparsity, scaled for the tiny model),
 partitions a synthetic classification task across 8 Non-IID clients
 (Dirichlet alpha=0.5), and runs high-frequency (T=1) MEERKAT rounds —
 clients upload one scalar per step, the server reconstructs their virtual
-paths and aggregates.  Runs on CPU; the ZO perturb/update dispatches
-through the fused Pallas kernels in interpret mode (``--zo-backend``).
+paths and aggregates.  Runs on CPU.
 """
 import argparse
 
@@ -27,8 +26,6 @@ from repro.models import Model
 ap = argparse.ArgumentParser()
 ap.add_argument("--rounds", type=int, default=150)
 ap.add_argument("--clients", type=int, default=8)
-ap.add_argument("--zo-backend", default="auto",
-                choices=["auto", "pallas", "ref"])
 a = ap.parse_args()
 
 spec = TaskSpec()
@@ -51,7 +48,7 @@ clients = [Client(k, subset(train, p), batch_size=16)
 
 # 3. high-frequency MEERKAT (T=1): scalar-only sync every local step
 fl = FLConfig(n_clients=a.clients, local_steps=1, lr=5e-2, eps=1e-3,
-              density=1e-2, zo_backend=a.zo_backend)
+              density=1e-2)
 server = FederatedZO(loss, params, space, fl, clients, eval_fn=evaluate)
 
 ev = sample_dataset(spec, 512, seed=2)

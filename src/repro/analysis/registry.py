@@ -50,7 +50,7 @@ def _tokens(*shape):
 def build_zo_train_loop() -> Built:
     """The compiled high-frequency training burst:
     ``fl_step.make_fl_train_loop`` (T=1 MEERKAT steps in one jitted
-    scan), fused flat route, 2 steps x 2 clients at S=320."""
+    scan), 2 steps x 2 clients at S=320."""
     import jax
 
     from repro.core.fl_step import make_fl_train_loop
@@ -63,7 +63,10 @@ def build_zo_train_loop() -> Built:
     return Built(
         jax.jit(loop), (params, jax.random.key(1), batches),
         meta=dict(seq_threshold=S, dyn_dims={"S": S},
-                  peak_bytes_budget=48 * MiB))   # measured ~24 MB
+                  # the liveness estimate (walk.liveness_peak_bytes) of
+                  # this program on the space.add route, traced on CPU:
+                  # 11.7 MB
+                  peak_bytes_budget=24 * MiB))
 
 
 def _round_problem():
@@ -91,8 +94,7 @@ def _group_fn(loss, space, *, T: int, eps=1e-3, lr=5e-2, sharded=False):
     import jax.numpy as jnp
 
     from repro.core import zo
-    run = zo.make_local_run(loss, space, eps, lr, n_dirs=1,
-                            backend="ref", sharded=sharded)
+    run = zo.make_local_run(loss, space, eps, lr, n_dirs=1, sharded=sharded)
 
     def group(params, keys, batches):
         zeros = jnp.zeros((space.n,), jnp.float32)
@@ -149,8 +151,7 @@ def build_fl_round_sharded() -> Built:
             plan.place_client_batches(batches, K))
 
     # live round on the same plan: the protocol's byte accounting
-    fl = FLConfig(n_clients=K, local_steps=T, lr=5e-2, eps=1e-3, seed=0,
-                  zo_backend="ref")
+    fl = FLConfig(n_clients=K, local_steps=T, lr=5e-2, eps=1e-3, seed=0)
     train = sample_dataset(TaskSpec(), 256, seed=1)
     parts = dirichlet_partition(train["label"], K, 0.5, seed=0)
     clients = [Client(k, subset(train, p), b) for k, p in enumerate(parts)]
@@ -196,8 +197,7 @@ def build_ckpt_roundtrip() -> Built:
     from repro.data.synthetic import TaskSpec, sample_dataset
     model, params, loss, space = _round_problem()
     K, T, b = 4, 2, 8
-    fl = FLConfig(n_clients=K, local_steps=T, lr=5e-2, eps=1e-3, seed=0,
-                  zo_backend="ref")
+    fl = FLConfig(n_clients=K, local_steps=T, lr=5e-2, eps=1e-3, seed=0)
     train = sample_dataset(TaskSpec(), 256, seed=1)
     parts = dirichlet_partition(train["label"], K, 0.5, seed=0)
 

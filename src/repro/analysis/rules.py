@@ -43,8 +43,8 @@ VMEM_BUDGETS = {"tpu": 16 * 2 ** 20, "tpu_v5e": 128 * 2 ** 20}
 F64_DTYPES = ("float64", "complex128")
 LOWP_DTYPES = ("bfloat16", "float16")
 # reductions whose accumulator dtype follows the (low-precision) output
-# aval — the kernels deliberately contract these to f32 (zo_update /
-# flash_attention keep f32 VMEM accumulators), so a low-precision aval
+# aval — the kernels deliberately contract these to f32 (flash_attention
+# keeps f32 VMEM accumulators), so a low-precision aval
 # here means silently lossy accumulation.
 REDUCE_PRIMS = ("reduce_sum", "cumsum", "dot_general", "add_any",
                 "reduce_window_sum", "reduce_prod")

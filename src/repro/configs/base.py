@@ -216,9 +216,10 @@ class FLConfig:
     mask_kind: str = "sensitivity"  # sensitivity | magnitude | random | dense | lora
     seed: int = 0
     batch_size: int = 16
-    # ZO hot-path execution route (core/dispatch.py): "auto" uses the fused
-    # flat Pallas kernels when the layout supports it, else the pytree route.
-    zo_backend: str = "auto"  # auto | pallas | ref
+    # the ZO loop has one route; "auto" and "ref" both name it.  The field
+    # stays only because the benchmark's traffic file sets it, and goes
+    # once that file drops the key.
+    zo_backend: str = "auto"
     # beyond-paper: K-direction ZO estimator per local step (core/zo.py);
     # clients then upload T*K scalars per round
     n_dirs: int = 1
@@ -244,6 +245,12 @@ class FLConfig:
     # uplink codec for the ZO scalars (core/quantize.py):
     # none | int8 | int4 [-nearest for deterministic rounding]
     quantize: str = "none"
+
+    def __post_init__(self):
+        if self.zo_backend not in ("auto", "ref"):
+            raise ValueError(
+                f"zo_backend must be 'auto' or 'ref', got {self.zo_backend!r}"
+                " (the flat 'pallas' ZO route was removed)")
 
 
 @dataclass(frozen=True)

@@ -1,5 +1,4 @@
 """MEERKAT core: the paper's contribution as composable JAX modules."""
-from repro.core.dispatch import FlatBacking, get_backing, resolve_backend
 from repro.core.fl_step import (make_fl_round_step, make_fl_train_loop,
                                 make_fl_train_step)
 from repro.core.gradip import (gradip_matrix, gradip_trajectory,

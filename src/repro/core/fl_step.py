@@ -11,26 +11,20 @@ high-frequency (T=1) MEERKAT step is exactly:
     w' = w - lr * mean_k(g_k) * z        (one sparse scatter)
 
 The only cross-client collective is the scalar mean — the paper's 1000x
-communication saving, visible structurally in the lowered HLO.
-
-Both step factories dispatch between the fused flat-vector Pallas route and
-the pytree reference route (``core/dispatch.py``).  On the flat route the
-perturb phase is one ``zo_dual_perturb_flat`` HBM pass producing both
-perturbed copies and the weight update one ``zo_fused_update_flat`` pass —
-versus three chained full-tree scatter passes on the reference route.
+communication saving, visible structurally in the lowered HLO.  Every
+perturb is a sparse scatter at the space's coordinates.
 
 ``make_fl_round_step`` is the T>1 variant (clients' deltas diverge within a
-round, so clients are vmapped; used by simulations and small-scale runs).
+round; used by simulations and small-scale runs).
 """
 from __future__ import annotations
 
-from typing import Callable, Optional
+from typing import Callable
 
 import jax
 import jax.numpy as jnp
 
-from repro.core.dispatch import get_backing, resolve_backend
-from repro.kernels.ops import zo_dual_perturb_flat, zo_fused_update_flat
+from repro.core.zo import make_local_run
 
 
 def _masked_mean(g_clients, report_mask):
@@ -46,7 +40,7 @@ def _masked_mean(g_clients, report_mask):
 
 def make_fl_train_step(per_example_loss: Callable, space, *, eps: float,
                        lr: float, n_clients: int, constrain_params=None,
-                       backend: Optional[str] = None, quantize=None):
+                       quantize=None):
     """T=1 high-frequency MEERKAT step (Alg. 3). Returns jittable fn
     (params, key, batch) -> (params', g_clients [K], metrics).
 
@@ -58,9 +52,6 @@ def make_fl_train_step(per_example_loss: Callable, space, *, eps: float,
     ``constrain_params`` re-applies the parameter sharding after each sparse
     scatter — the flat-index scatter otherwise erases GSPMD's weight
     shardings and replicates all downstream matmuls (see DESIGN.md §perf).
-    When it is set, backend="auto" resolves to the pytree route: flattening
-    a tensor-parallel weight is not GSPMD-representable, so the fused flat
-    kernels are reserved for the unsharded / FSDP-only regimes.
 
     The optional trailing ``report_mask`` ([K] 0/1) is the compiled-path
     dropout model: clients whose upload was lost are excluded from the
@@ -71,208 +62,73 @@ def make_fl_train_step(per_example_loss: Callable, space, *, eps: float,
     cp = constrain_params or (lambda p: p)
 
     def step(params, key, batch, report_mask=None):
-        backing = get_backing(space, params)
-        be = resolve_backend(backend, backing,
-                             sharded=constrain_params is not None)
         z = space.sample_z(key)
-        if be == "ref":
-            w_plus = cp(space.add(params, eps * z))
-            l_plus = per_example_loss(w_plus, batch)          # [B_global]
-            w_minus = cp(space.add(w_plus, (-2.0 * eps) * z))  # in-place chain
-            l_minus = per_example_loss(w_minus, batch)
-        else:
-            w_flat = backing.flatten(params)
-            z_flat = backing.expand(z)
-            wp, wm = zo_dual_perturb_flat(w_flat, z_flat, None, eps)
-            l_plus = per_example_loss(cp(backing.unflatten(wp)), batch)
-            l_minus = per_example_loss(cp(backing.unflatten(wm)), batch)
+        w_plus = cp(space.add(params, eps * z))
+        l_plus = per_example_loss(w_plus, batch)           # [B_global]
+        w_minus = cp(space.add(w_plus, (-2.0 * eps) * z))  # in-place chain
+        l_minus = per_example_loss(w_minus, batch)
         g_clients = (l_plus - l_minus).reshape(n_clients, -1).mean(-1) \
             / (2.0 * eps)
         if quantize is not None:
             g_clients = quantize.apply(g_clients, key)
         g = _masked_mean(g_clients, report_mask)          # scalar collective
-        if be == "ref":
-            new_params = cp(space.add(w_minus, (eps - lr * g) * z))
-        else:
-            new_params = cp(backing.unflatten(zo_fused_update_flat(
-                w_flat, z_flat, None, -lr * g)))
+        new_params = cp(space.add(w_minus, (eps - lr * g) * z))
         metrics = {"loss": jnp.mean(l_plus + l_minus) / 2.0, "g": g}
         return new_params, g_clients, metrics
 
     return step
 
 
-# Below this many backed parameters the per-step cost is dominated by op
-# dispatch, and stacking (w+, w-) into one vmapped forward halves the
-# dispatch count; above it the forwards are compute/memory-bound and the
-# 2x-batch stacked matmuls lose to two sequential forwards (measured on
-# both bench arches: tiny wants stacked, qwen3-4b-reduced wants
-# sequential — BENCH_zo_step.json).
-STACK_FORWARDS_MAX_PARAMS = 1 << 20
-
-
 def make_fl_train_loop(per_example_loss: Callable, space, *, eps: float,
                        lr: float, n_clients: int, n_steps: int,
-                       backend: Optional[str] = None,
-                       stack_forwards: Optional[bool] = None,
                        constrain_params=None, quantize=None):
     """``n_steps`` T=1 high-frequency MEERKAT steps in one jitted scan —
-    the compiled training burst (the serving engine's decode-burst idea
-    applied to the train loop: no host round-trip per step).
+    the compiled training burst (no host round-trip per step).
 
     Returns jittable (params, key, batches[, report_masks]) -> (params',
-    g_clients [n_steps, K], metrics), with batches carrying a leading
-    [n_steps, ...] axis.  Semantically identical to folding
-    :func:`make_fl_train_step` over the batches.
+    g_clients [n_steps, K], metrics of the last step), with batches
+    carrying a leading [n_steps, ...] axis.  The scan body is
+    :func:`make_fl_train_step`'s step at the step keys
+    ``jax.random.split(key, n_steps)``; ``constrain_params`` and
+    ``quantize`` mean what they mean there.
 
     The optional trailing ``report_masks`` ([n_steps, K] 0/1) is the
     per-step survivor/cohort mask, a *runtime operand* scanned alongside
     the batches: sampled cohorts and dropout patterns change per step
-    without recompiling.  ``quantize`` mirrors
-    :func:`make_fl_train_step`: per-client scalars are rounded to the
-    uplink wire grid (key folded per step) before the masked mean.
-
-    On the fused route the flat parameter vector is built ONCE before the
-    scan and carried dense across it — the per-step
-    ``backing.flatten(params)`` / tile re-padding round-trip that repeated
-    single-step calls pay (and that inverted the e2e fused-vs-naive
-    comparison on qwen3_4b in BENCH_zo_step) is hoisted; each scanned step
-    is exactly one fused dual-perturb pass, the two forwards, and one
-    fused update pass.
-
-    ``stack_forwards`` picks how the fused route evaluates the (w+, w-)
-    pair: True stacks both into one vmapped 2x-batch forward (halves op
-    dispatch — wins when the model is small enough that dispatch dominates),
-    False runs two sequential forwards (wins once the forwards are
-    compute-bound and the 2x-batch matmuls stop fitting cache).  None
-    auto-selects by backed-parameter count (STACK_FORWARDS_MAX_PARAMS).
-
-    ``constrain_params`` is the mesh route (mirroring
-    :func:`make_fl_train_step`): it re-applies the plan's weight shardings
-    after every sparse scatter inside the scanned burst, and forces
-    ``backend="auto"`` onto the pytree route — the flat carry is not
-    GSPMD-representable for sharded weights (DESIGN.md §perf/§9)."""
-    cp = constrain_params or (lambda p: p)
+    without recompiling."""
+    step = make_fl_train_step(per_example_loss, space, eps=eps, lr=lr,
+                              n_clients=n_clients,
+                              constrain_params=constrain_params,
+                              quantize=quantize)
 
     def loop(params, key, batches, report_masks=None):
-        backing = get_backing(space, params)
+        def one(p, inp):
+            k, b, mask = inp
+            p, g_cl, metrics = step(p, k, b, mask)
+            return p, (g_cl, metrics)
+
         keys = jax.random.split(key, n_steps)
-        xs = ((keys, batches) if report_masks is None
-              else (keys, batches, report_masks))
-
-        def unpack(inp):
-            return inp if report_masks is not None else (*inp, None)
-
-        def g_of(l_plus, l_minus, k):
-            g_cl = (l_plus - l_minus).reshape(n_clients, -1).mean(-1) \
-                / (2.0 * eps)
-            if quantize is not None:
-                g_cl = quantize.apply(g_cl, k)
-            return g_cl
-
-        if resolve_backend(backend, backing,
-                           sharded=constrain_params is not None) == "ref":
-            def one(p, inp):
-                k, b, mask = unpack(inp)
-                z = space.sample_z(k)
-                w_plus = cp(space.add(p, eps * z))
-                l_plus = per_example_loss(w_plus, b)
-                w_minus = cp(space.add(w_plus, (-2.0 * eps) * z))
-                l_minus = per_example_loss(w_minus, b)
-                g_cl = g_of(l_plus, l_minus, k)
-                g = _masked_mean(g_cl, mask)
-                new_p = cp(space.add(w_minus, (eps - lr * g) * z))
-                return new_p, (g_cl, (l_plus + l_minus).mean() / 2.0)
-
-            p_T, (gs, losses) = jax.lax.scan(one, params, xs)
-            return p_T, gs, {"loss": losses[-1], "g": gs[-1].mean()}
-
-        w0 = backing.flatten(params)  # hoisted: once per burst, not per step
-        # one dense z buffer carried across the burst: the coordinate set
-        # is static, so each step overwrites only the sparse values in
-        # place instead of re-materializing n_pad zeros (scatter_into)
-        z0 = jnp.zeros((backing.n_pad,), jnp.float32)
-        stack = (backing.n_flat <= STACK_FORWARDS_MAX_PARAMS
-                 if stack_forwards is None else stack_forwards)
-
-        def one(carry, inp):
-            w_flat, z_buf = carry
-            k, b, mask = unpack(inp)
-            z_flat = backing.scatter_into(z_buf, space.sample_z(k))
-            wp, wm = zo_dual_perturb_flat(w_flat, z_flat, None, eps)
-            if stack:
-                # one vectorized forward over the stacked (w+, w-) pair:
-                # identical math (vmap), half the per-step op dispatches on
-                # the loss side — the small-model bottleneck the flat route
-                # pays twice
-                both = jax.vmap(per_example_loss, in_axes=(0, None))(
-                    jax.vmap(lambda f: cp(backing.unflatten(f)))(
-                        jnp.stack([wp, wm])), b)
-                l_plus, l_minus = both[0], both[1]
-            else:
-                l_plus = per_example_loss(cp(backing.unflatten(wp)), b)
-                l_minus = per_example_loss(cp(backing.unflatten(wm)), b)
-            g_cl = g_of(l_plus, l_minus, k)
-            g = _masked_mean(g_cl, mask)
-            new_w = zo_fused_update_flat(w_flat, z_flat, None, -lr * g)
-            return (new_w, z_flat), (g_cl, (l_plus + l_minus).mean() / 2.0)
-
-        (w_T, _), (gs, losses) = jax.lax.scan(one, (w0, z0), xs)
-        return (cp(backing.unflatten(w_T)), gs,
-                {"loss": losses[-1], "g": gs[-1].mean()})
+        p_T, (gs, metrics) = jax.lax.scan(one, params,
+                                          (keys, batches, report_masks))
+        return p_T, gs, jax.tree.map(lambda m: m[-1], metrics)
 
     return loop
 
 
-def make_fl_round_step(loss_fn: Callable, space, *, eps: float, lr: float,
-                       T: int, backend: Optional[str] = None):
-    """Full MEERKAT round with T>1 local steps and vmapped clients.
+def make_fl_round_step(loss_fn: Callable, space, *, eps: float, lr: float):
+    """Full MEERKAT round with T local steps per client (Alg. 2).
 
     batches: pytree with leading [K, T, b, ...]; keys: [T] (shared across
-    clients per Alg. 2).  Returns (params', gs [K, T]).
-
-    Flat route: the parameter vector is flattened once per round; each
-    vmapped client carries its dense flat delta through the T-step scan with
-    one fused dual-perturb + one fused update pass per step."""
-
-    def client_run_ref(params, keys, batches_c):
-        def one(delta, inp):
-            key, b = inp
-            z = space.sample_z(key)
-            lp = loss_fn(space.add(params, delta + eps * z), b)
-            lm = loss_fn(space.add(params, delta - eps * z), b)
-            g = (lp - lm) / (2.0 * eps)
-            return delta - lr * g * z, g
-
-        delta0 = jnp.zeros((space.n,), jnp.float32)
-        return jax.lax.scan(one, delta0, (keys, batches_c))
+    clients).  Returns (params', gs [K, T]).  Each client runs
+    :func:`repro.core.zo.make_local_run`'s loop under ``jax.lax.map``, as
+    the server's group program does; the mean of their deltas is applied
+    with ``space.add``."""
+    run = make_local_run(loss_fn, space, eps, lr)
 
     def round_step(params, keys, batches):
-        backing = get_backing(space, params)
-        n_cl = jax.tree_util.tree_leaves(batches)[0].shape[0]
-        if resolve_backend(backend, backing, dense_carry=n_cl) == "ref":
-            deltas, gs = jax.vmap(client_run_ref, in_axes=(None, None, 0))(
-                params, keys, batches)
-        else:
-            w_flat = backing.flatten(params)
-
-            def client_run(batches_c):
-                def one(delta_dense, inp):
-                    key, b = inp
-                    z_flat = backing.expand(space.sample_z(key))
-                    wp, wm = zo_dual_perturb_flat(w_flat + delta_dense,
-                                                  z_flat, None, eps)
-                    lp = loss_fn(backing.unflatten(wp), b)
-                    lm = loss_fn(backing.unflatten(wm), b)
-                    g = (lp - lm) / (2.0 * eps)
-                    return zo_fused_update_flat(delta_dense, z_flat, None,
-                                                -lr * g), g
-
-                d0 = jnp.zeros((backing.n_pad,), jnp.float32)
-                d_T, gs = jax.lax.scan(one, d0, (keys, batches_c))
-                return backing.restrict(d_T), gs
-
-            deltas, gs = jax.vmap(client_run)(batches)
+        zeros = jnp.zeros((space.n,), jnp.float32)
+        deltas, gs = jax.lax.map(lambda b: run(params, keys, b, zeros),
+                                 batches)
         agg = jnp.mean(deltas, axis=0)                    # [n] sparse collective
         return space.add(params, agg), gs
 

@@ -5,8 +5,7 @@ ZO-reconstructed client gradient.  In sparse coordinates this is simply
 ``g_k^t * dot(gp[mask], z_t)`` — the server never materializes dense
 gradients.
 
-The inner reduction dispatches like the other hot paths
-(``core/dispatch.py`` pattern):
+The inner reduction has two routes:
 
 * ``backend="pallas"`` — the blocked Pallas reduction
   (``kernels/gradip_reduce.py`` via ``kernels/ops.gradip_flat``): ``gp``

@@ -52,7 +52,6 @@ from repro.core import seeds as S
 from repro.core import virtual_path as VP
 from repro.core import vpcs as VPCS
 from repro.core import zo as ZO
-from repro.core.dispatch import get_backing, resolve_backend
 from repro.core.gradip import gradip_trajectory
 from repro.core.quantize import make_codec
 from repro.core.sampling import ClientSampler
@@ -127,13 +126,7 @@ class FederatedZO:
         ``fl.sample_weighted``).  ``None`` with ``sample_frac == 1``
         runs the whole fleet every round (today's dense protocol).
       codec: optional uplink codec override (``core/quantize.py``); by
-        default built from ``fl.quantize`` (``"none"`` = raw f32).
-
-    The vmapped client loops dispatch through ``fl.zo_backend``
-    ("auto" routes the per-step perturb/update through the fused flat
-    Pallas kernels when the layout supports it; see core/dispatch.py).
-    Under a ``plan`` the auto backend resolves to the pytree route, whose
-    N-D scatters keep weight leaves sharded."""
+        default built from ``fl.quantize`` (``"none"`` = raw f32)."""
 
     def __init__(self, loss_fn: Callable, params, space, fl: FLConfig,
                  clients: Sequence[Client], eval_fn: Optional[Callable] = None,
@@ -144,7 +137,6 @@ class FederatedZO:
         self.fl = fl
         self.plan = plan
         self.params = params if plan is None else plan.place_params(params)
-        self.backend = getattr(fl, "zo_backend", "auto")
         self.clients = list(clients)
         self.eval_fn = eval_fn
         self.high_freq = fl.local_steps == 1 if high_freq is None else high_freq
@@ -173,8 +165,8 @@ class FederatedZO:
         # (T, group width) -> abstract (shape, dtype, sharding) arguments
         # of that group program's first call (``group_hlo_text``)
         self._group_args: Dict[tuple, tuple] = {}
-        # (T, group width) -> the ZO route ("pallas" | "ref") its program
-        # runs, and the host seconds of each round ``run`` completed
+        # (T, group width) -> the ZO route its program runs (there is one,
+        # "ref"), and the host seconds of each round ``run`` completed
         self.zo_routes: Dict[tuple, str] = {}
         self.round_seconds: List[float] = []
         # per-client GradIP [T_cali] of the last calibrate_vp call
@@ -186,10 +178,8 @@ class FederatedZO:
 
         self._recon = jax.jit(replay)
 
-    # -- jitted vmapped T-step client group (one compile per distinct
-    # (T, group width); the width feeds the auto backend's dense-carry
-    # budget, so a small early-stopped group isn't penalized for the
-    # fleet size) ------------------------------------------------------
+    # -- jitted T-step client group (one compile per distinct
+    # (T, group width)) ----------------------------------------------
     def _batch_run_for(self, T: int, n_group: int, template_batches=None):
         """Jitted ``(params, keys [T], batches [K, T, b, ...]) ->
         (deltas [K, n], gs [K, T] or [K, T, n_dirs])`` for a group of
@@ -206,18 +196,12 @@ class FederatedZO:
         key = (T, n_group)
         if key not in self._batch_runs:
             obs.count("fl.programs_built")
-            # resolve the ZO route here, once per group program, so the
-            # route each group took is observable (``zo_routes``)
-            route = resolve_backend(
-                self.backend, get_backing(self.space, self.params),
-                sharded=self.plan is not None, dense_carry=n_group)
-            self.zo_routes[key] = route
+            self.zo_routes[key] = "ref"
             # only rule "tp" runs the loop on GSPMD-sharded leaves; under
             # shard_group each device holds its clients' whole weights
             run = ZO.make_local_run(self.loss_fn, self.space, self.fl.eps,
                                     self.fl.lr,
                                     n_dirs=getattr(self.fl, "n_dirs", 1),
-                                    backend=route,
                                     sharded=(self.plan is not None
                                              and self.plan.rule == "tp"),
                                     quantize=self.codec.jax_spec())

@@ -13,6 +13,8 @@ All operations are jittable; index trees can be abstract for the dry-run.
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -23,41 +25,7 @@ def _leaves_with_paths(tree):
     return [(jax.tree_util.keystr(p), l) for p, l in flat]
 
 
-class _FlatSpace:
-    """Flat-vector backing shared by every space (kernel dispatch).
-
-    Subclasses provide ``leaf_index_arrays(template)`` — per-leaf int32 flat
-    indices of the selected coordinates, in the same leaf order as
-    ``tree_leaves(template)``.  The derived :class:`repro.core.dispatch.
-    FlatBacking` (cached per layout) maps the space into the single flat
-    [N] vector the fused Pallas ZO kernels consume.
-    """
-
-    def leaf_index_arrays(self, template):
-        raise NotImplementedError
-
-    def identity_layout(self) -> bool:
-        """True if this space structurally covers every coordinate in
-        storage order — lets the backing skip index materialization
-        entirely (no O(N) arange build/compare for e.g. Full-FedZO)."""
-        return False
-
-    def flat_backing(self, template):
-        from repro.core.dispatch import get_backing
-        return get_backing(self, template)
-
-    def flatten(self, params):
-        """Pytree -> flat [n_pad] vector (leaf-concatenation order, zero
-        tail up to the kernels' (8, 128) tile quantum)."""
-        return self.flat_backing(params).flatten(params)
-
-    def unflatten(self, flat, template):
-        """Flat [n_pad] (or [N]) vector -> pytree with the template's
-        shapes/dtypes; the padded tail is ignored."""
-        return self.flat_backing(template).unflatten(flat)
-
-
-class MaskedSpace(_FlatSpace):
+class MaskedSpace:
     """Sparse coordinate space from per-leaf flat index arrays.
 
     ``idx_tree`` has the same treedef as ``params``; each leaf is an int32
@@ -108,11 +76,20 @@ class MaskedSpace(_FlatSpace):
                 for l, idx in zip(t_leaves, i_leaves)]
         return jnp.concatenate(segs) if segs else jnp.zeros((0,), jnp.float32)
 
-    def leaf_index_arrays(self, template):
-        return jax.tree_util.tree_leaves(self.idx_tree)
+    @functools.cached_property
+    def sorted_unique(self) -> bool:
+        """Whether every leaf's indices are strictly increasing (so sorted
+        and unique), checked once on the concrete index leaves.  A traced
+        index tree (the dry-run's abstract masks) never qualifies.  Only
+        then may a scatter at them declare ``indices_are_sorted`` /
+        ``unique_indices``: the index contract allows any order, and a
+        false declaration gives wrong numbers."""
+        leaves = jax.tree_util.tree_leaves(self.idx_tree)
+        return not any(isinstance(i, jax.core.Tracer) for i in leaves) and \
+            all(bool(np.all(np.diff(np.asarray(i)) > 0)) for i in leaves)
 
 
-class DenseSpace(_FlatSpace):
+class DenseSpace:
     """All parameters, flattened (Full-FedZO)."""
 
     def __init__(self, template):
@@ -138,15 +115,8 @@ class DenseSpace(_FlatSpace):
         return jnp.concatenate([l.reshape(-1).astype(jnp.float32)
                                 for l in leaves])
 
-    def leaf_index_arrays(self, template):
-        return [jnp.arange(int(np.prod(l.shape)), dtype=jnp.int32)
-                for l in jax.tree_util.tree_leaves(template)]
 
-    def identity_layout(self) -> bool:
-        return True
-
-
-class LoRASpace(_FlatSpace):
+class LoRASpace:
     """Only ``lora_*`` adapter leaves (dense within the adapters)."""
 
     def __init__(self, template):
@@ -179,12 +149,3 @@ class LoRASpace(_FlatSpace):
         segs = [l.reshape(-1).astype(jnp.float32)
                 for l, m in zip(leaves, self._is_lora) if m]
         return jnp.concatenate(segs)
-
-    def leaf_index_arrays(self, template):
-        leaves = jax.tree_util.tree_leaves(template)
-        return [jnp.arange(int(np.prod(l.shape)), dtype=jnp.int32) if m
-                else jnp.zeros((0,), jnp.int32)
-                for l, m in zip(leaves, self._is_lora)]
-
-    def identity_layout(self) -> bool:
-        return all(self._is_lora)

@@ -6,41 +6,38 @@ grad_hat = g * (z (.) m)
 We sample z only at the masked coordinates (space semantics), which is
 mathematically identical to the dense ``z (.) m`` formulation.
 
-Every entry point dispatches between two execution routes (see
-``core/dispatch.py``):
+Every entry point perturbs the weights through the space's sparse ``[n]``
+vectors; nothing dense in the parameter count is built.  The T-step
+client loop (:func:`make_local_run`) has two bodies:
 
-* ``backend="pallas"`` — the hot path.  Parameters live as one flat [N]
-  vector; each perturb phase is a single fused
-  :func:`repro.kernels.ops.zo_dual_perturb_flat` pass (one HBM read of
-  (w, z, m) producing both perturbed copies) and each update a single
-  :func:`repro.kernels.ops.zo_fused_update_flat` pass, instead of chained
-  per-leaf pytree scatters.
-* ``backend="ref"``    — the original ``space.add`` pytree route (reference
-  semantics; required for sharded weights and odd layouts).  Its T-step
-  loop over a :class:`MaskedSpace` at one direction perturbs in place
-  (:func:`_run_ref_inplace`), bit for bit the same as ``space.add``.
-* ``backend=None``/"auto" picks pallas whenever the flat layout supports it.
+* a :class:`MaskedSpace` at one direction — the server's path in every
+  cell — perturbs a loop-carried working copy of the touched leaves in
+  place (:func:`_run_inplace`): point scatters through each leaf's
+  (8, 128) tile view, or, for a leaf that holds many coordinates under a
+  TPU compile, the block-wise Pallas kernel of ``kernels/block_place.py``;
+* ``n_dirs > 1`` and the other spaces scan :func:`local_step`, which
+  perturbs with ``space.add``.
 
-Both routes name their phases with ``jax.named_scope`` — ``zo.sample``
-(z and its dense scatter), ``zo.perturb`` (the perturbed parameters),
-``zo.forward`` (the two loss evaluations) and ``zo.update`` (g and the
-delta update) — so each compiled instruction's ``op_name`` metadata says
-which phase it belongs to.  Scopes change metadata only, never numbers.
+The two write the same bits wherever both apply.
+
+Both name their phases with ``jax.named_scope`` — ``zo.sample`` (z),
+``zo.perturb`` (the perturbed parameters), ``zo.forward`` (the two loss
+evaluations) and ``zo.update`` (g and the delta update) — so each compiled
+instruction's ``op_name`` metadata says which phase it belongs to.  Scopes
+change metadata only, never numbers.
 """
 from __future__ import annotations
 
-from typing import Callable, Optional
+from typing import Callable
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro import obs
-from repro.core.dispatch import get_backing, resolve_backend
 from repro.core.spaces import MaskedSpace
 from repro.kernels import block_place
-from repro.kernels.ops import (_default_interpret, zo_dual_perturb_flat,
-                               zo_fused_update_flat)
+from repro.kernels.ops import _default_interpret
 
 
 def _maybe_quantize(g, key, quantize):
@@ -49,85 +46,29 @@ def _maybe_quantize(g, key, quantize):
     applying it, so the value it uploads (and the server dequantizes) is
     bit-identical to the value its local trajectory used.  The rounding
     noise key is the step/direction key folded with QUANT_FOLD — a
-    stream disjoint from z sampling, derivable from the seed ladder.
-    Identical in the ref and flat-kernel routes (backend bit-parity)."""
+    stream disjoint from z sampling, derivable from the seed ladder."""
     if quantize is None:
         return g
     return quantize.apply(g, key)
 
 
-def _dual_losses(loss_fn, backing, base_flat, z_flat, eps, batch):
-    """Fused perturb + the two loss evaluations; returns (l+, l-).
-
-    z_flat comes pre-masked from ``backing.expand`` (zero off the space
-    coordinates), so the kernels run without the mask operand stream."""
+def projected_gradient(loss_fn: Callable, params, space, delta, z, eps: float,
+                       batch):
+    """Scalar projected gradient g at (params + delta) along z."""
     with jax.named_scope("zo.perturb"):
-        w_plus, w_minus = zo_dual_perturb_flat(base_flat, z_flat, None, eps)
-        p_plus = backing.unflatten(w_plus)
+        p_plus = space.add(params, delta + eps * z)
     with jax.named_scope("zo.forward"):
         lp = loss_fn(p_plus, batch)
     with jax.named_scope("zo.perturb"):
-        p_minus = backing.unflatten(w_minus)
+        p_minus = space.add(params, delta - eps * z)
     with jax.named_scope("zo.forward"):
-        return lp, loss_fn(p_minus, batch)
-
-
-def _multi_dir_update(loss_fn, backing, space, base_flat, key, eps: float,
-                      n_dirs: int, batch, quantize=None):
-    """K-direction fused estimator at ``base_flat``: splits the step key
-    into K direction keys (matching ``reconstruct_delta``'s [T, K] replay)
-    and returns (mean_k g_k * z_k as a dense flat vector, gs [K]).
-
-    Scanned with a running sum so peak dense memory stays one [n_pad]
-    accumulator (not [K, n_pad]) and the loss graph compiles once."""
-
-    def one(acc, k):
-        with jax.named_scope("zo.sample"):
-            z_flat = backing.expand(space.sample_z(k))
-        lp, lm = _dual_losses(loss_fn, backing, base_flat, z_flat, eps,
-                              batch)
-        with jax.named_scope("zo.update"):
-            g = _maybe_quantize((lp - lm) / (2.0 * eps), k, quantize)
-            return acc + g * z_flat, g
-
-    upd_sum, gs = jax.lax.scan(one, jnp.zeros((backing.n_pad,), jnp.float32),
-                               jax.random.split(key, n_dirs))
-    return upd_sum / n_dirs, gs
-
-
-def projected_gradient(loss_fn: Callable, params, space, delta, z, eps: float,
-                       batch, backend: Optional[str] = None,
-                       sharded: bool = False):
-    """Scalar projected gradient g at (params + delta) along z.
-
-    ``sharded=True`` declares that ``params`` live sharded on a mesh, so
-    ``backend="auto"`` resolves to the pytree route (the flat reshape is
-    not GSPMD-representable; see core/dispatch.py)."""
-    backing = get_backing(space, params)
-    if resolve_backend(backend, backing, sharded=sharded) == "ref":
-        with jax.named_scope("zo.perturb"):
-            p_plus = space.add(params, delta + eps * z)
-        with jax.named_scope("zo.forward"):
-            lp = loss_fn(p_plus, batch)
-        with jax.named_scope("zo.perturb"):
-            p_minus = space.add(params, delta - eps * z)
-        with jax.named_scope("zo.forward"):
-            lm = loss_fn(p_minus, batch)
-        with jax.named_scope("zo.update"):
-            return (lp - lm) / (2.0 * eps)
-    with jax.named_scope("zo.perturb"):
-        base = backing.flatten(params) + backing.expand(delta)
-    with jax.named_scope("zo.sample"):
-        z_flat = backing.expand(z)
-    lp, lm = _dual_losses(loss_fn, backing, base, z_flat, eps, batch)
+        lm = loss_fn(p_minus, batch)
     with jax.named_scope("zo.update"):
         return (lp - lm) / (2.0 * eps)
 
 
 def local_step(loss_fn: Callable, params, space, delta, key, eps: float,
-               lr: float, batch, n_dirs: int = 1,
-               backend: Optional[str] = None, sharded: bool = False,
-               quantize=None):
+               lr: float, batch, n_dirs: int = 1, quantize=None):
     """One client-side ZO step on the sparse delta. Returns (delta', g).
 
     ``n_dirs > 1`` (beyond-paper) averages the estimator over K independent
@@ -139,36 +80,14 @@ def local_step(loss_fn: Callable, params, space, delta, key, eps: float,
 
     ``quantize`` (a :class:`repro.core.quantize.QuantSpec`) rounds each
     g to the uplink wire grid before the update — exact-replay mode: the
-    applied scalar equals the dequantized upload bit-for-bit."""
-    backing = get_backing(space, params)
-    if resolve_backend(backend, backing, sharded=sharded) == "ref":
-        return _local_step_ref(loss_fn, params, space, delta, key, eps, lr,
-                               batch, n_dirs, quantize)
+    applied scalar equals the dequantized upload bit-for-bit.
 
-    with jax.named_scope("zo.perturb"):
-        base = backing.flatten(params) + backing.expand(delta)
+    It perturbs through ``space.add``; at ``n_dirs > 1`` the summation
+    order of the mean over the vmapped directions fixes its bits."""
     if n_dirs == 1:
         with jax.named_scope("zo.sample"):
             z = space.sample_z(key)
-            z_flat = backing.expand(z)
-        lp, lm = _dual_losses(loss_fn, backing, base, z_flat, eps, batch)
-        with jax.named_scope("zo.update"):
-            g = _maybe_quantize((lp - lm) / (2.0 * eps), key, quantize)
-            return delta - lr * g * z, g
-
-    upd, gs = _multi_dir_update(loss_fn, backing, space, base, key, eps,
-                                n_dirs, batch, quantize)
-    with jax.named_scope("zo.update"):
-        return delta - lr * backing.restrict(upd), gs
-
-
-def _local_step_ref(loss_fn, params, space, delta, key, eps, lr, batch,
-                    n_dirs, quantize=None):
-    if n_dirs == 1:
-        with jax.named_scope("zo.sample"):
-            z = space.sample_z(key)
-        g = projected_gradient(loss_fn, params, space, delta, z, eps, batch,
-                               backend="ref")
+        g = projected_gradient(loss_fn, params, space, delta, z, eps, batch)
         with jax.named_scope("zo.update"):
             g = _maybe_quantize(g, key, quantize)
             return delta - lr * g * z, g
@@ -176,8 +95,7 @@ def _local_step_ref(loss_fn, params, space, delta, key, eps, lr, batch,
     def one(k):
         with jax.named_scope("zo.sample"):
             z = space.sample_z(k)
-        g = projected_gradient(loss_fn, params, space, delta, z, eps, batch,
-                               backend="ref")
+        g = projected_gradient(loss_fn, params, space, delta, z, eps, batch)
         with jax.named_scope("zo.update"):
             g = _maybe_quantize(g, k, quantize)
             return g * z, g
@@ -229,9 +147,10 @@ def _tile_positions(idx: np.ndarray, shape) -> np.ndarray:
             * _TILE_R * _TILE_C + r % _TILE_R * _TILE_C + c % _TILE_C)
 
 
-def _run_ref_inplace(loss_fn, params, space, backing, keys, batches, delta0,
-                     eps, lr, quantize=None, sharded: bool = False):
-    """The ref route's T-step loop at one direction, perturbing in place.
+def _run_inplace(loss_fn, params, space, keys, batches, delta0, eps, lr,
+                 quantize=None, sharded: bool = False):
+    """The T-step loop of a :class:`MaskedSpace` at one direction,
+    perturbing in place.
 
     The leaves that hold mask coordinates are copied once into a working
     set that the step loop carries, and their values at the coordinates,
@@ -243,7 +162,7 @@ def _run_ref_inplace(loss_fn, params, space, backing, keys, batches, delta0,
     by the scatters, so XLA updates the carried leaves in place rather
     than copying every touched leaf twice a step.
 
-    Where the backing has checked the concrete indices sorted and unique,
+    Where the space has checked its concrete indices sorted and unique,
     the scatters declare so (XLA then sorts no indices per step), and
     unless ``sharded`` each tileable leaf is carried in its tile view and
     scattered through that view's 1-D flattening, at its coordinates'
@@ -271,7 +190,7 @@ def _run_ref_inplace(loss_fn, params, space, backing, keys, batches, delta0,
     p_leaves, treedef = jax.tree_util.tree_flatten(params)
     i_leaves = jax.tree_util.tree_leaves(space.idx_tree)
     live = [k for k, i in enumerate(i_leaves) if i.shape[0]]
-    exact = backing.sorted_unique
+    exact = space.sorted_unique
     flags = (dict(indices_are_sorted=True, unique_indices=True)
              if exact else {})
     tiled = {k for k in live
@@ -357,79 +276,33 @@ def _run_ref_inplace(loss_fn, params, space, backing, keys, batches, delta0,
 
 
 def make_local_run(loss_fn: Callable, space, eps: float, lr: float,
-                   n_dirs: int = 1, backend: Optional[str] = None,
-                   sharded: bool = False,
-                   quantize=None):
+                   n_dirs: int = 1, sharded: bool = False, quantize=None):
     """Jittable T-step client loop.
 
     batches: pytree with leading [T, ...]; keys: [T] PRNG keys.
     Returns (delta_T [n], gs [T]) (gs: [T, K] when n_dirs > 1).
-    ``sharded=True`` declares mesh-sharded parameters: ``backend="auto"``
-    then takes the pytree route, whose N-D scatters keep the weight
-    leaves sharded (DESIGN.md §9), and the in-place perturb scatters each
-    leaf in its own shape.
+    ``sharded=True`` declares GSPMD-sharded parameters: the in-place
+    perturb then scatters each leaf at N-D indices in its own shape, which
+    keeps the weight leaves sharded (DESIGN.md §9).
     ``quantize`` (:class:`repro.core.quantize.QuantSpec`) turns on
     exact-replay uplink quantization: each step's g is rounded to the
     wire grid before it is applied *and* before it is returned, so the
     trajectory is bit-reconstructible from the quantized upload.
 
-    On the pallas backend the flat parameter vector is built ONCE outside
-    the scan and the scan carries the *dense* flat delta, so every local
-    step is exactly one fused dual-perturb pass plus one fused update pass
-    over HBM — no per-step pytree scatter chain.  On the ref backend a
-    :class:`MaskedSpace` at ``n_dirs == 1`` perturbs a carried working copy
-    of its touched leaves in place (:func:`_run_ref_inplace`); ``n_dirs >
-    1`` keeps the vmapped ``space.add`` step, whose mean's summation order
-    fixes its bits."""
+    A :class:`MaskedSpace` at ``n_dirs == 1`` perturbs a carried working
+    copy of its touched leaves in place (:func:`_run_inplace`); ``n_dirs >
+    1`` and the other spaces scan :func:`local_step`."""
 
     def run(params, keys, batches, delta0):
-        backing = get_backing(space, params)
-        if resolve_backend(backend, backing, sharded=sharded) == "ref":
-            if n_dirs == 1 and isinstance(space, MaskedSpace):
-                return _run_ref_inplace(loss_fn, params, space, backing,
-                                        keys, batches, delta0, eps, lr,
-                                        quantize, sharded)
+        if n_dirs == 1 and isinstance(space, MaskedSpace):
+            return _run_inplace(loss_fn, params, space, keys, batches,
+                                delta0, eps, lr, quantize, sharded)
 
-            def step(delta, inp):
-                key, batch = inp
-                delta, g = _local_step_ref(loss_fn, params, space, delta,
-                                           key, eps, lr, batch, n_dirs,
-                                           quantize)
-                return delta, g
-
-            return jax.lax.scan(step, delta0, (keys, batches))
-
-        with jax.named_scope("zo.perturb"):
-            w_flat = backing.flatten(params)
-        # dense z buffer carried across the scan: the coordinate set is
-        # static, so each step refreshes the sparse values in place
-        # (scatter_into) instead of re-materializing n_pad zeros
-        with jax.named_scope("zo.sample"):
-            z0 = jnp.zeros((backing.n_pad,), jnp.float32)
-
-        def step(carry, inp):
-            delta_dense, z_buf = carry
+        def step(delta, inp):
             key, batch = inp
-            with jax.named_scope("zo.perturb"):
-                base = w_flat + delta_dense
-            if n_dirs == 1:
-                with jax.named_scope("zo.sample"):
-                    z_flat = backing.scatter_into(z_buf, space.sample_z(key))
-                lp, lm = _dual_losses(loss_fn, backing, base, z_flat, eps,
-                                      batch)
-                with jax.named_scope("zo.update"):
-                    g = _maybe_quantize((lp - lm) / (2.0 * eps), key,
-                                        quantize)
-                    return (zo_fused_update_flat(delta_dense, z_flat, None,
-                                                 -lr * g), z_flat), g
-            upd, gs = _multi_dir_update(loss_fn, backing, space, base, key,
-                                        eps, n_dirs, batch, quantize)
-            with jax.named_scope("zo.update"):
-                return (zo_fused_update_flat(delta_dense, upd, None, -lr),
-                        z_buf), gs
+            return local_step(loss_fn, params, space, delta, key, eps, lr,
+                              batch, n_dirs, quantize)
 
-        (delta_T, _), gs = jax.lax.scan(step, (backing.expand(delta0), z0),
-                                        (keys, batches))
-        return backing.restrict(delta_T), gs
+        return jax.lax.scan(step, delta0, (keys, batches))
 
     return run

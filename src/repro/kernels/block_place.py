@@ -1,6 +1,6 @@
 """Block-wise placement of sparse values into a leaf, in place.
 
-The ref route's in-place perturb (``core/zo.py:_run_ref_inplace``) writes
+The ZO loop's in-place perturb (``core/zo.py:_run_inplace``) writes
 ``n`` values at static, sorted, distinct positions of a weight leaf viewed
 as ``[M, 128]`` rows of its (8, 128) tiles.  A point scatter does that one
 value at a time.  This kernel rewrites the leaf one ``[bm, 128]`` row block

@@ -2,8 +2,8 @@
 
 On this CPU container kernels always run in interpret mode; on a real TPU
 set ``interpret=False`` (the default flips on TPU platforms automatically).
-The flat-vector helpers pad/reshape 1-D inputs into the (R, 128) tile layout
-the kernels expect.
+The flat-vector helper pads/reshapes 1-D inputs into the (R, 128) tile
+layout the GradIP kernel expects.
 """
 from __future__ import annotations
 
@@ -16,69 +16,17 @@ import jax.numpy as jnp
 from repro.kernels.decode_attention import decode_attention
 from repro.kernels.flash_attention import flash_attention as \
     flash_attention_kernel
-from repro.kernels.gradip_reduce import LANE, gradip_reduce
-from repro.kernels.zo_update import BLOCK_R, SUB, dual_perturb, fused_update
+from repro.kernels.gradip_reduce import LANE, SUB, gradip_reduce
 
 
 def _default_interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
-def _fit_block_r(n: int, interpret: bool) -> int:
-    """Row-block for a flat [n] vector.
-
-    Compiled (TPU): BLOCK_R rows per block — 128 KiB f32 operand tiles that
-    fit VMEM — unless the vector is smaller, in which case just enough
-    (8, 128) sublane tiles to hold it (tiny spaces don't pad to 32K elems).
-    Interpret (CPU tests/sims): one grid step covering the whole vector —
-    the interpreter costs milliseconds *per grid step*, and there is no
-    VMEM bound to respect, so blocking would only multiply that overhead."""
-    r_needed = -(-n // LANE)
-    r8 = -(-r_needed // SUB) * SUB
-    return r8 if interpret else min(BLOCK_R, r8)
-
-
 def _tile(v, block_r: int):
     """Pad a flat [N] vector to [R, 128] with R % block_r == 0."""
-    n = v.shape[0]
-    per = LANE * block_r
-    pad = (-n) % per
-    return jnp.pad(v, (0, pad)).reshape(-1, LANE), n
-
-
-@functools.partial(jax.jit, static_argnames=("block_r", "interpret"))
-def zo_dual_perturb_flat(w_flat, z_flat, m_flat, eps, *,
-                         block_r: int | None = None,
-                         interpret: bool | None = None):
-    """Flat-vector fused dual perturbation: returns (w+, w-) of shape [N].
-
-    ``m_flat=None`` means z is already zero off the sparse coordinates
-    (pre-masked by the dispatch layer) — the mask stream is skipped."""
-    interpret = _default_interpret() if interpret is None else interpret
-    n = w_flat.shape[0]
-    block_r = _fit_block_r(n, interpret) if block_r is None else block_r
-    w2, _ = _tile(w_flat, block_r)
-    z2, _ = _tile(z_flat, block_r)
-    m2 = None if m_flat is None else _tile(m_flat, block_r)[0]
-    p, m_ = dual_perturb(w2, z2, m2, eps, block_r=block_r,
-                         interpret=interpret)
-    return p.reshape(-1)[:n], m_.reshape(-1)[:n]
-
-
-@functools.partial(jax.jit, static_argnames=("block_r", "interpret"))
-def zo_fused_update_flat(w_flat, z_flat, m_flat, scale, *,
-                         block_r: int | None = None,
-                         interpret: bool | None = None):
-    """``m_flat=None``: pre-masked z, see :func:`zo_dual_perturb_flat`."""
-    interpret = _default_interpret() if interpret is None else interpret
-    n = w_flat.shape[0]
-    block_r = _fit_block_r(n, interpret) if block_r is None else block_r
-    w2, _ = _tile(w_flat, block_r)
-    z2, _ = _tile(z_flat, block_r)
-    m2 = None if m_flat is None else _tile(m_flat, block_r)[0]
-    out = fused_update(w2, z2, m2, scale, block_r=block_r,
-                       interpret=interpret)
-    return out.reshape(-1)[:n]
+    pad = (-v.shape[0]) % (LANE * block_r)
+    return jnp.pad(v, (0, pad)).reshape(-1, LANE)
 
 
 @functools.partial(jax.jit, static_argnames=("block_r", "interpret"))
@@ -86,9 +34,8 @@ def gradip_flat(gp_flat, z_flat, g, *, block_r: int = 256,
                 interpret: bool | None = None):
     """GradIP = g * <gp, z> over flat sparse-coordinate vectors."""
     interpret = _default_interpret() if interpret is None else interpret
-    gp2, _ = _tile(gp_flat, block_r)
-    z2, _ = _tile(z_flat, block_r)
-    return gradip_reduce(gp2, z2, g, block_r=block_r, interpret=interpret)
+    return gradip_reduce(_tile(gp_flat, block_r), _tile(z_flat, block_r), g,
+                         block_r=block_r, interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("block_s", "softcap",

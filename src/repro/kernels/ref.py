@@ -5,15 +5,6 @@ import jax
 import jax.numpy as jnp
 
 
-def dual_perturb_ref(w, z, m, eps):
-    pert = (eps * z * m).astype(w.dtype)
-    return w + pert, w - pert
-
-
-def fused_update_ref(w, z, m, scale):
-    return w + (scale * z * m).astype(w.dtype)
-
-
 def gradip_reduce_ref(gp, z, g):
     return jnp.asarray(g, jnp.float32) * jnp.sum(
         gp.astype(jnp.float32) * z.astype(jnp.float32))

@@ -117,9 +117,6 @@ def main(argv=None):
     ap.add_argument("--density", type=float, default=1e-2)
     ap.add_argument("--batch", type=int, default=16)
     ap.add_argument("--seed", type=int, default=0)
-    ap.add_argument("--zo-backend", default="auto",
-                    choices=["auto", "pallas", "ref"],
-                    help="ZO perturb/update route (core/dispatch.py)")
     ap.add_argument("--attn-backend", default="auto",
                     choices=["auto", "pallas", "online", "dense"],
                     help="forward-attention route for the ZO loss forwards")
@@ -235,7 +232,6 @@ def _train(a, ap):
 
     fl = FLConfig(n_clients=a.clients, rounds=a.rounds, local_steps=a.T,
                   lr=a.lr, eps=a.eps, density=a.density, seed=a.seed,
-                  zo_backend=a.zo_backend,
                   batch_size=a.batch, vp_calibration_steps=100,
                   vp_init_steps=20, vp_later_steps=20, vp_rho_later=2.0,
                   vp_sigma=0.25, vp_sigma_relative=True,

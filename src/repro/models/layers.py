@@ -539,12 +539,11 @@ DECODE_BACKENDS = ("auto", "pallas", "ref")
 def resolve_decode_backend(backend, cfg, ctx=None) -> str:
     """Map a requested decode-attention backend to 'pallas' | 'ref'.
 
-    Mirrors ``core/dispatch.resolve_backend``: "auto" prefers the Pallas
-    flash-decode kernel (interpret mode off-TPU, see kernels/ops.py) and
-    falls back to the grouped jnp path for layouts the kernel does not
-    cover — a sharded mesh (the jnp path carries the GSPMD sharding
-    constraints) or, compiled on a real TPU, a head_dim off the 128-lane
-    tile."""
+    "auto" prefers the Pallas flash-decode kernel (interpret mode
+    off-TPU, see kernels/ops.py) and falls back to the grouped jnp path
+    for layouts the kernel does not cover — a sharded mesh (the jnp path
+    carries the GSPMD sharding constraints) or, compiled on a real TPU, a
+    head_dim off the 128-lane tile."""
     backend = backend or "auto"
     if backend not in DECODE_BACKENDS:
         raise ValueError(

@@ -44,7 +44,7 @@ def prob():
 
 def mk_server(prob, plan=None, momentum=0.5, n_clients=3, T=2):
     fl = FLConfig(n_clients=n_clients, local_steps=T, batch_size=2,
-                  server_momentum=momentum, zo_backend="ref")
+                  server_momentum=momentum)
     clients = [Client(i, sample_dataset(SPEC, 8, seed=i), 2)
                for i in range(n_clients)]
     return FederatedZO(prob["loss"], prob["params"], prob["space"], fl,

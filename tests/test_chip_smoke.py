@@ -27,8 +27,8 @@ def test_train_phase(arch):
     assert rep["dtype"] == ["float32"]
     assert len(rep["losses"]) == 2
     assert len(rep["smoke_round_s"]["steady"]) == 1
-    # CPU-sized layouts fit the host budget: every group on the flat route
-    assert set(rep["zo_routes"].values()) == {"pallas"}
+    # every group on the one ZO route
+    assert set(rep["zo_routes"].values()) == {"ref"}
     assert "T=100 x2" in rep["zo_routes"]  # the VP calibration group
     assert rep["mask_changed_share"] > 0.5
     if arch == "qwen2-1.5b-reduced":
@@ -47,7 +47,7 @@ def test_serve_phase(arch):
 
 def test_kernel_phase():
     rep = chip_smoke.kernel_phase("qwen2-1.5b-reduced", B=4, S=128)
-    assert rep["zo_dual_perturb_exact"] and rep["zo_fused_update_exact"]
+    assert rep["block_place_exact"]
     assert max(v for v in rep.values() if isinstance(v, float)) <= rep["rtol"]
 
 

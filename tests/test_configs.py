@@ -83,3 +83,13 @@ def test_long_context_gate():
     for n in ("qwen3-4b", "qwen2-7b", "chatglm3-6b", "pixtral-12b",
               "whisper-small", "kimi-k2-1t-a32b", "phi3.5-moe-42b-a6.6b"):
         assert not get_config(n).supports_long_context, n
+
+
+def test_fl_config_zo_backend_names_the_one_route():
+    """"auto" and "ref" both name the one ZO route; the removed flat
+    route is refused by name."""
+    from repro.configs.base import FLConfig
+    assert FLConfig().zo_backend == "auto"
+    assert FLConfig(zo_backend="ref").zo_backend == "ref"
+    with pytest.raises(ValueError, match="pallas"):
+        FLConfig(zo_backend="pallas")

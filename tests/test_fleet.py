@@ -49,7 +49,7 @@ def prob():
 def mk_server(prob, n_clients=6, T=2, frac=1.0, quantize="none",
               weighted=False, plan=None, sampler=None, codec=None):
     fl = FLConfig(n_clients=n_clients, local_steps=T, batch_size=2,
-                  zo_backend="ref", sample_frac=frac, quantize=quantize,
+                  sample_frac=frac, quantize=quantize,
                   sample_weighted=weighted)
     clients = [Client(i, sample_dataset(SPEC, 8, seed=i), 2)
                for i in range(n_clients)]
@@ -320,7 +320,7 @@ def test_quantized_loop_matches_codec_grid(prob):
     codec = IntCodec(bits=8, stochastic=True)
     loop = make_fl_train_loop(prob["per_example"], prob["space"], eps=1e-3,
                               lr=1e-2, n_clients=4, n_steps=3,
-                              backend="ref", quantize=codec.jax_spec())
+                              quantize=codec.jax_spec())
     batch = sample_dataset(SPEC, 4 * 2 * 3, seed=0)
     batches = {k: jnp.asarray(v).reshape(3, 4 * 2, *np.shape(v)[1:])
                for k, v in batch.items()}
@@ -333,8 +333,7 @@ def test_loop_report_masks_all_ones_is_dense(prob):
     """report_masks as a runtime operand: all-ones masks match the
     maskless loop bitwise (one compiled program for every cohort)."""
     loop = make_fl_train_loop(prob["per_example"], prob["space"], eps=1e-3,
-                              lr=1e-2, n_clients=4, n_steps=2,
-                              backend="ref")
+                              lr=1e-2, n_clients=4, n_steps=2)
     batch = sample_dataset(SPEC, 4 * 2 * 2, seed=0)
     batches = {k: jnp.asarray(v).reshape(2, 4 * 2, *np.shape(v)[1:])
                for k, v in batch.items()}

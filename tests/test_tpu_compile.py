@@ -25,7 +25,6 @@ import jax.numpy as jnp  # noqa: E402
 from jax.sharding import SingleDeviceSharding  # noqa: E402
 
 from repro.configs import get_config  # noqa: E402
-from repro.core.dispatch import _TILE  # noqa: E402
 from repro.kernels import ops  # noqa: E402
 from repro.models.init import param_count  # noqa: E402
 
@@ -60,20 +59,6 @@ def _compile_hlo(fn, *args) -> str:
     hlo = jax.jit(fn).lower(*args).compile().as_text()
     assert "tpu_custom_call" in hlo
     return hlo
-
-
-def test_zo_flat_kernels_compile(one_chip):
-    # a flat vector the size of the tied embedding, the largest leaf (the
-    # whole model's flat route needs ~23 GB and is refused for HBM: auto
-    # sends full-width groups down the pytree route, core/dispatch.py)
-    n_pad = -(-CFG.vocab * CFG.d_model // _TILE) * _TILE
-    w = _sds((n_pad,), jnp.bfloat16, one_chip)
-    z = _sds((n_pad,), jnp.float32, one_chip)
-    eps = _sds((), jnp.float32, one_chip)
-    _compile_hlo(lambda w, z, e: ops.zo_dual_perturb_flat(
-        w, z, None, e, interpret=False), w, z, eps)
-    _compile_hlo(lambda w, z, e: ops.zo_fused_update_flat(
-        w, z, None, e, interpret=False), w, z, eps)
 
 
 def test_gradip_reduce_compiles(one_chip):
@@ -180,13 +165,13 @@ def _hot_mask(abstract, hot: float, rest: float):
 
 def _check_group_in_place(one_chip, cfg, hot=None):
     """The client group program the server builds (4 clients x 5 ZO steps
-    on the ``ref`` route, a random mask at density 1e-3, or ``hot``'s
+    in place, a random mask at density 1e-3, or ``hot``'s
     (density of ``wo``, of the rest)) at ``cfg``'s widths, two layers deep
     (the leaves are stacked, and their minor dims set the layout).  The
     in-place perturb leaves no whole-leaf copy or relayout of a masked
     leaf, and no sort of a leaf's indices, inside the step loop.  Each leaf
     the block kernel places (``kernels/block_place.py``, as the rule in
-    ``_run_ref_inplace`` routes under a TPU compile) is written by two
+    ``_run_inplace`` routes under a TPU compile) is written by two
     Pallas custom calls a step and by no scatter; the counters split the
     coordinates as the rule does."""
     from repro import obs
@@ -204,7 +189,7 @@ def _check_group_in_place(one_chip, cfg, hot=None):
              if hot is None else _hot_mask(abstract, *hot))
     loss, _, _ = make_task_fns(model, TaskSpec(
         vocab=cfg.vocab, n_classes=4, seq_len=S))
-    run = make_local_run(loss, space, 1e-3, 5e-2, backend="ref")
+    run = make_local_run(loss, space, 1e-3, 5e-2)
 
     def group(params, keys, batches):
         zeros = jnp.zeros((space.n,), jnp.float32)

@@ -1,9 +1,11 @@
-"""The ref route's in-place perturb (``core/zo.py:_run_ref_inplace``)
-against a plain oracle built from ``MaskedSpace.add`` through
+"""The ZO loop's in-place perturb (``core/zo.py:_run_inplace``) against a
+plain oracle built from ``MaskedSpace.add`` through
 ``projected_gradient``: the same client group under ``jax.lax.map``, the
 same T-step scan, bit for bit.  Under a TPU compile the leaves that hold
 many coordinates are placed block by block (``kernels/block_place.py``);
-here that route runs in the Pallas interpreter, forced by the test."""
+here that route runs in the Pallas interpreter, forced by the test.  With
+``sharded=True`` (GSPMD-sharded weights, ``rule="tp"``) every leaf is
+scattered at N-D indices in its own shape instead."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -12,7 +14,6 @@ import pytest
 from repro import obs
 from repro.core import MaskedSpace, make_local_run, projected_gradient
 from repro.core import zo
-from repro.core.dispatch import get_backing
 from repro.core.quantize import QuantSpec
 from repro.core.zo import _from_tiles, _maybe_quantize, _to_tiles
 from repro.kernels import block_place
@@ -107,7 +108,7 @@ def _oracle(space, quantize, T):
             key, batch = inp
             z = space.sample_z(key)
             g = projected_gradient(_loss, params, space, delta, z, EPS,
-                                   batch, backend="ref")
+                                   batch)
             g = _maybe_quantize(g, key, quantize)
             return delta - LR * g * z, g
 
@@ -118,8 +119,8 @@ def _oracle(space, quantize, T):
     return jax.jit(run)
 
 
-def _group(space, quantize):
-    run = make_local_run(_loss, space, EPS, LR, backend="ref",
+def _group(space, quantize, sharded=False):
+    run = make_local_run(_loss, space, EPS, LR, sharded=sharded,
                          quantize=quantize)
 
     def group(params, keys, batches):
@@ -144,7 +145,7 @@ def test_inplace_matches_space_add_bitwise(dtype, T, quant, mask,
                                            monkeypatch):
     params = _params(jnp.dtype(dtype), wide=mask == "blocks")
     space = _space(params, mask)
-    assert get_backing(space, params).sorted_unique == (mask != "permuted")
+    assert space.sorted_unique == (mask != "permuted")
     quantize = QuantSpec(bits=8) if quant else None
     K, b, S = 3, 2, 8
     rng = np.random.default_rng(T)
@@ -190,6 +191,38 @@ def test_inplace_matches_space_add_bitwise(dtype, T, quant, mask,
     np.testing.assert_array_equal(np.asarray(b_deltas), np.asarray(deltas))
 
 
+@pytest.mark.parametrize("mask", ["all", "some_empty", "permuted", "blocks"])
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+def test_inplace_sharded_matches_space_add_bitwise(dtype, mask,
+                                                   monkeypatch):
+    """``sharded=True`` carries every leaf in its own shape and scatters
+    at N-D indices, as ``MaskedSpace.add`` does: the same bits, and no
+    coordinate left to the block kernel even where a TPU compile would
+    place the leaf by blocks unsharded."""
+    params = _params(jnp.dtype(dtype), wide=mask == "blocks")
+    space = _space(params, mask)
+    K, T, b, S = 2, 3, 2, 8
+    rng = np.random.default_rng(T)
+    batches = {"tokens": jnp.asarray(rng.integers(0, V, (K, T, b, S)),
+                                     jnp.int32),
+               "label": jnp.asarray(rng.integers(0, V, (K, T, b, S)),
+                                    jnp.int32)}
+    keys = jax.random.split(jax.random.key(11), T)
+    monkeypatch.setattr(zo, "_default_interpret", lambda: False)
+    before = _counters()
+    deltas, gs = _group(space, None, sharded=True)(params, keys, batches)
+    after = _counters()
+    assert after["zo.perturb.block_coords"] == before[
+        "zo.perturb.block_coords"]
+    assert after["zo.perturb.point_coords"] == before[
+        "zo.perturb.point_coords"] + space.n
+    want_deltas, want_gs = _oracle(space, None, T)(params, keys, batches)
+    np.testing.assert_array_equal(np.asarray(gs), np.asarray(want_gs))
+    np.testing.assert_array_equal(np.asarray(deltas),
+                                  np.asarray(want_deltas))
+    assert np.all(np.asarray(gs) != 0)
+
+
 def test_multi_direction_and_dense_keep_space_add():
     """``n_dirs > 1`` and spaces without an index tree take no in-place
     path."""
@@ -201,8 +234,7 @@ def test_multi_direction_and_dense_keep_space_add():
     before = obs.totals()["counters"].get("zo.perturb_inplace", 0)
     for space, n_dirs in [(_space(params, "all"), 2),
                           (DenseSpace(params), 1)]:
-        run = make_local_run(_loss, space, EPS, LR, n_dirs=n_dirs,
-                             backend="ref")
+        run = make_local_run(_loss, space, EPS, LR, n_dirs=n_dirs)
         jax.jit(run)(params, keys, batch, jnp.zeros((space.n,)))
     assert obs.totals()["counters"].get("zo.perturb_inplace", 0) == before
 

@@ -79,14 +79,9 @@ def make_clients(prob, n_clients: int, batch: int = 16):
 
 
 def run_server(prob, plan, *, T: int, rounds: int, n_clients: int):
-    """One full MEERKAT-VP run; returns everything parity compares.
-
-    ``zo_backend="ref"`` on both sides: the mesh route resolves to the
-    pytree backend, so the single-device reference must run the same
-    route for a bit-level comparison (pallas-vs-ref parity is covered
-    separately in tests/test_dispatch.py)."""
+    """One full MEERKAT-VP run; returns everything parity compares."""
     fl = FLConfig(n_clients=n_clients, local_steps=T, lr=5e-2, eps=1e-3,
-                  seed=0, zo_backend="ref", vp_calibration_steps=8,
+                  seed=0, vp_calibration_steps=8,
                   vp_init_steps=4, vp_later_steps=4, vp_rho_later=2.0,
                   vp_sigma=0.25, vp_sigma_relative=True)
     srv = FederatedZO(prob["loss"], prob["params"], prob["space"], fl,
@@ -112,7 +107,7 @@ def run_hf_loop(prob, plan, *, n_steps: int, n_clients: int, batch: int = 8):
     _, per_example, _ = make_task_fns(model, SPEC)
     loop = make_fl_train_loop(
         lambda p, b: per_example(p, b), prob["space"], eps=1e-3, lr=5e-2,
-        n_clients=n_clients, n_steps=n_steps, backend="ref",
+        n_clients=n_clients, n_steps=n_steps,
         constrain_params=None if plan is None else plan.constrain_params_fn())
     B = n_clients * batch
     data = sample_dataset(SPEC, n_steps * B, seed=7)

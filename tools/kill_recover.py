@@ -25,10 +25,7 @@ Mesh-reshape recovery: ``--mesh-b 2x2`` runs the victim sharded on a
 with ``--mesh-a/--mesh-c``) — checkpoints are mesh-portable, so the
 survivor may restore onto a different topology than the one that died.
 Each subprocess forces its own host device count from its ``--mesh``
-flag, so the drill itself needs no XLA_FLAGS.  ``--zo-backend ref`` is
-pinned on every run: mesh routes resolve to the pytree backend, and
-bit-comparison across topologies needs both sides on the same route
-(DESIGN.md §9).
+flag, so the drill itself needs no XLA_FLAGS.
 
 A CPU harness: the three runs get ``JAX_PLATFORMS=cpu`` unless the
 caller set it, so they never contend for an accelerator.
@@ -66,7 +63,6 @@ def train_cmd(a, ckpt_dir: str, *, mesh=None, kill_at=None, resume=False):
            "--rounds", str(a.rounds), "--T", str(a.T),
            "--clients", str(a.clients), "--batch", str(a.batch),
            "--seed", str(a.seed), "--eval-every", str(a.eval_every),
-           "--zo-backend", "ref",
            "--checkpoint-dir", ckpt_dir, "--checkpoint-every", "1"]
     if a.sample_frac < 1.0:
         cmd += ["--sample-frac", str(a.sample_frac)]
