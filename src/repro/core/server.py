@@ -177,6 +177,13 @@ class FederatedZO:
                 self.space, keys, g, self.fl.lr))(gs)
 
         self._recon = jax.jit(replay)
+        # the round's device program after the replay (aggregate, then
+        # update), and the space's index operands: a MaskedSpace's
+        # per-leaf indices, committed once beside the parameters; the
+        # dense spaces hold none
+        self._update = jax.jit(self._aggregate_update)
+        self._idx = (jax.device_put(space.idx_tree) if plan is None
+                     else plan.place_replicated(space.idx_tree))
 
     # -- jitted T-step client group (one compile per distinct
     # (T, group width)) ----------------------------------------------
@@ -224,6 +231,32 @@ class FederatedZO:
                     group, template_batches, n_group,
                     out_ndims=(2, 3 if n_dirs > 1 else 2)))
         return self._batch_runs[key]
+
+    def _aggregate_update(self, params, idx_tree, deltas, n_report,
+                          velocity):
+        """``(params, idx_tree, deltas [K, n], n_report, velocity) ->
+        (leaves, velocity)``: the mean of the replayed deltas over the
+        reporting count (a zero update when ``K`` is 0), FedAvgM momentum
+        when ``fl.server_momentum`` is set, then ``space.add`` at the
+        space's coordinates, all in one program.  It returns only the
+        leaves the space holds coordinates in (``space.sizes``), so no
+        other leaf is copied; the leaves it does not read are pruned from
+        its arguments.
+
+        The indices and the count are operands, never constants: the
+        compiler cannot fold them, so the bits equal ``VP.aggregate`` and
+        ``space.add`` run op by op.  One trace per distinct ``K`` (and
+        once more when momentum's velocity first exists); the counter
+        ``fl.update_programs`` counts the traces."""
+        obs.count("fl.update_programs")
+        agg = (VP.aggregate(deltas, n_report) if deltas.shape[0]
+               else jnp.zeros((self.space.n,), jnp.float32))
+        if self.fl.server_momentum > 0.0:
+            velocity = (agg if velocity is None
+                        else self.fl.server_momentum * velocity + agg)
+            agg = velocity
+        new = jax.tree.leaves(self.space.add(params, agg, idx_tree))
+        return [l for l, m in zip(new, self.space.sizes) if m], velocity
 
     def _client_T(self, cid: int) -> int:
         return 1 if cid in self.early_stopped else self.fl.local_steps
@@ -278,7 +311,8 @@ class FederatedZO:
         """Execute one round: group clients by local-step count T, run each
         group's local ZO loops (vmapped; sharded under a ``plan``), account
         the scalar uploads, reconstruct every client's virtual path from
-        (seed list, scalars) on the host, aggregate, and apply the update.
+        (seed list, scalars) gathered to the host, then aggregate and apply
+        the update in one device program (:meth:`_aggregate_update`).
 
         ``gp_vec`` ([n] pre-training gradient): also log each client's
         GradIP trajectory for this round.  Returns {cid: gs [T] or
@@ -435,24 +469,24 @@ class FederatedZO:
             from repro.fault import plan as _fault_plan
             _fault_plan.kill_now()  # mid-round: work done, update not applied
         # (3) aggregate the reconstructed sparse updates of whoever reported
-        # (+ optional FedAvgM server momentum — beyond-paper)
+        # (+ optional FedAvgM server momentum — beyond-paper) and apply
+        # them: one transfer of the [K, n] stack, then one program
+        # (a zero-survivor round applies a zero update through it)
         with obs.span("fl.aggregate"):
-            n_report = sum(int(d.shape[0]) for d in deltas)
-            if n_report:
-                agg = VP.aggregate(
-                    jnp.concatenate([jnp.asarray(d) for d in deltas], axis=0),
-                    n_report)
-            else:  # zero-survivor round: well-defined no-op update
-                agg = jnp.zeros((self.space.n,), jnp.float32)
-            if self.fl.server_momentum > 0.0:
-                self.velocity = (agg if self.velocity is None
-                                 else self.fl.server_momentum * self.velocity
-                                 + agg)
-                agg = self.velocity
-            if self.plan is not None:
-                agg = self.plan.place_replicated(agg)
+            stack = (np.concatenate(deltas, axis=0) if deltas
+                     else np.zeros((0, self.space.n), np.float32))
+            n_report = int(stack.shape[0])
+            stack = (jax.device_put(stack) if self.plan is None
+                     else self.plan.place_replicated(stack))
         with obs.span("fl.update"):
-            self.params = self.space.add(self.params, agg)
+            live, self.velocity = self._update(
+                self.params, self._idx, stack, np.float32(n_report),
+                self.velocity)
+            leaves, treedef = jax.tree.flatten(self.params)
+            live = iter(live)
+            self.params = treedef.unflatten(
+                [next(live) if m else l
+                 for l, m in zip(leaves, self.space.sizes)])
         self.round += 1
         self.last_round_info = dict(
             round=r, n_reporting=n_report, drops=sorted(f.drops),

@@ -48,16 +48,21 @@ class MaskedSpace:
         return [vec[self.offsets[i]:self.offsets[i + 1]]
                 for i in range(len(self.sizes))]
 
-    def add(self, params, vec):
+    def add(self, params, vec, idx_tree=None):
         """params + scatter(vec) at the masked coordinates.
 
         Uses N-D scatter indices (``unravel_index`` of the stored flat
         indices) rather than reshaping the leaf to 1-D: a flat reshape is not
         representable for tensor-parallel shardings, so GSPMD would
         all-gather the weight; the N-D scatter keeps the operand sharded and
-        only replicates the (tiny) index/update vectors."""
+        only replicates the (tiny) index/update vectors.
+
+        ``idx_tree`` (default: the space's own) is the same indices passed
+        by a jitted caller as an operand, so its program does not fold
+        them in as constants (the server's update, ``core/server.py``)."""
         p_leaves, treedef = jax.tree_util.tree_flatten(params)
-        i_leaves = jax.tree_util.tree_leaves(self.idx_tree)
+        i_leaves = jax.tree_util.tree_leaves(
+            self.idx_tree if idx_tree is None else idx_tree)
         segs = self._segments(vec)
         out = []
         for p, idx, s in zip(p_leaves, i_leaves, segs):
@@ -92,6 +97,8 @@ class MaskedSpace:
 class DenseSpace:
     """All parameters, flattened (Full-FedZO)."""
 
+    idx_tree = None  # no indices: ``add`` takes ``idx_tree`` and ignores it
+
     def __init__(self, template):
         leaves = jax.tree_util.tree_leaves(template)
         self.template = template
@@ -102,7 +109,7 @@ class DenseSpace:
     def sample_z(self, key):
         return jax.random.normal(key, (self.n,), jnp.float32)
 
-    def add(self, params, vec):
+    def add(self, params, vec, idx_tree=None):
         p_leaves, treedef = jax.tree_util.tree_flatten(params)
         out = []
         for i, p in enumerate(p_leaves):
@@ -119,6 +126,8 @@ class DenseSpace:
 class LoRASpace:
     """Only ``lora_*`` adapter leaves (dense within the adapters)."""
 
+    idx_tree = None  # no indices: ``add`` takes ``idx_tree`` and ignores it
+
     def __init__(self, template):
         self._is_lora = [("lora_" in path)
                          for path, _ in _leaves_with_paths(template)]
@@ -133,7 +142,7 @@ class LoRASpace:
     def sample_z(self, key):
         return jax.random.normal(key, (self.n,), jnp.float32)
 
-    def add(self, params, vec):
+    def add(self, params, vec, idx_tree=None):
         p_leaves, treedef = jax.tree_util.tree_flatten(params)
         out = []
         for i, (p, m) in enumerate(zip(p_leaves, self._is_lora)):

@@ -70,9 +70,14 @@ def aggregate(deltas, n_reporting=None):
     given — and must match it unless a caller deliberately rescales
     (e.g. normalizing by the full fleet to damp partial rounds).  A
     zero-survivor round has no rows to average: callers apply a zero
-    update instead of calling this with an empty stack."""
-    n = deltas.shape[0] if n_reporting is None else int(n_reporting)
-    if n <= 0 or deltas.shape[0] == 0:
+    update instead of calling this with an empty stack.
+
+    Inside a jitted program ``n_reporting`` may be a traced operand (the
+    server's update passes it so, not as a constant that the compiler
+    could fold); the caller then keeps it positive."""
+    n = deltas.shape[0] if n_reporting is None else n_reporting
+    if deltas.shape[0] == 0 or (not isinstance(n, jax.core.Tracer)
+                                and int(n) <= 0):
         raise ValueError(
             f"aggregate needs >= 1 reporting client (got rows="
             f"{deltas.shape[0]}, n_reporting={n_reporting}); zero-survivor "

@@ -57,6 +57,13 @@ def test_vpcs_flags_equal_across_meshes(parity_report):
         assert parity_report["meshes"][spec]["vpcs_flags_equal"], spec
 
 
+def test_updated_leaves_keep_their_shardings(parity_report):
+    """The server's aggregate-and-update program returns every parameter
+    leaf with the sharding the plan placed it with."""
+    for spec in ("1x1", "2x2"):
+        assert parity_report["meshes"][spec]["shardings_kept"], spec
+
+
 def test_comm_bytes_accounting_invariant_under_sharding(parity_report):
     """The FL protocol traffic (scalar uploads, seed/scalar downlinks) is a
     property of the algorithm, not of the round's mesh layout."""

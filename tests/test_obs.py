@@ -154,9 +154,10 @@ def test_round_records_fl_spans_in_order_with_comm_bytes(prob):
         "up_bytes": srv.comm.up_bytes, "down_bytes": srv.comm.down_bytes}}
     # one group program built, for the first round only, and traced once
     # on the ref route's in-place perturb, which off the TPU leaves every
-    # coordinate to point scatters
+    # coordinate to point scatters; one aggregate-and-update program traced
     assert obs.export()["counters"] == {
         "fl.programs_built": 1, "zo.perturb_inplace": 1,
+        "fl.update_programs": 1,
         "zo.perturb.block_coords": 0,
         "zo.perturb.point_coords": prob["space"].n}
     first = [c for c in obs.export()["compiles"]

@@ -7,6 +7,8 @@ requested mesh spec (``sharding/fl.FLShardPlan``), then asserts:
 * round-aggregated parameters **bit-match** (``rule="fsdp"``/"replicate"),
 * per-client GradIP trajectories bit-match,
 * VPCS flag sets are identical,
+* the updated parameter leaves keep the shardings the plan placed them
+  with,
 * CommLog byte accounting is identical (the FL protocol traffic must not
   depend on how the round is sharded),
 * the ``make_fl_train_loop`` mesh route (global batch over the mesh batch
@@ -86,11 +88,16 @@ def run_server(prob, plan, *, T: int, rounds: int, n_clients: int):
                   vp_sigma=0.25, vp_sigma_relative=True)
     srv = FederatedZO(prob["loss"], prob["params"], prob["space"], fl,
                       make_clients(prob, n_clients), plan=plan)
+    placed = [l.sharding for l in jax.tree.leaves(srv.params)]
     _, flagged, _ = srv.calibrate_vp(prob["gp"])
     for _ in range(rounds):
         srv.run_round(gp_vec=prob["gp"])
     return dict(
         params=flat_params(srv.params),
+        # the server's update returns each leaf with the sharding it was
+        # placed with (a spec may drop its trailing Nones)
+        shardings_kept=all(l.sharding.is_equivalent_to(s, l.ndim) for l, s
+                           in zip(jax.tree.leaves(srv.params), placed)),
         gradip={cid: np.stack(v) for cid, v in srv.gradip_log.items()},
         flags=sorted(srv.early_stopped),
         comm=(srv.comm.up_bytes, srv.comm.down_bytes))
@@ -164,12 +171,13 @@ def main():
                 np.array_equal(ref["gradip"][c], got["gradip"][c])
                 for c in ref["gradip"]),
             "vpcs_flags_equal": ref["flags"] == got["flags"],
+            "shardings_kept": got["shardings_kept"],
             "comm_bytes_equal": ref["comm"] == got["comm"],
             "hf_loop_allclose": bool(
                 np.allclose(ref_hf["params"], got_hf["params"], atol=2e-5)
                 and np.allclose(ref_hf["gs"], got_hf["gs"], atol=2e-4)),
         }
-        required = ["params_allclose", "vpcs_flags_equal",
+        required = ["params_allclose", "vpcs_flags_equal", "shardings_kept",
                     "comm_bytes_equal", "hf_loop_allclose"]
         if bit_exact_rule:
             required += ["params_bitmatch", "gradip_bitmatch"]
